@@ -108,6 +108,9 @@ def select(benches, only=None, skip=None) -> list:
 
 
 def main(argv=None) -> int:
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description="run every benchmark harness")
     ap.add_argument("--json-dir", default=None,
                     help="redirect BENCH_*.json trajectory persistence "

@@ -44,6 +44,7 @@ import jax
 import numpy as np
 
 from repro.core import AdaSEGConfig
+from repro.launch.cache import enable_compile_cache
 from repro.obs import save_trace_events, validate_trace_events
 from repro.optim import MinimaxWorker, segda
 from repro.problems import make_bilinear_game
@@ -66,6 +67,7 @@ N = 10
 
 
 def main():
+    enable_compile_cache()
     game = make_bilinear_game(jax.random.PRNGKey(0), n=N, sigma=0.1)
     problem = heterogeneous_bilinear(game, M, jax.random.PRNGKey(1), alpha=0.4)
     pscfg = PSConfig(

@@ -11,10 +11,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import AdaSEGConfig, run_local_adaseg
+from repro.launch.cache import enable_compile_cache
 from repro.problems import make_bilinear_game
 
 
 def main():
+    enable_compile_cache()
     game = make_bilinear_game(jax.random.PRNGKey(0), n=10, sigma=0.1)
     cfg = AdaSEGConfig(
         g0=1.0,                        # guess of the gradient bound G

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from repro.configs import list_archs, smoke_config
 from repro.core import AdaSEGConfig
+from repro.launch.cache import enable_compile_cache
 from repro.models import (
     ModelWorker,
     decode_step,
@@ -53,6 +54,7 @@ def _demo_engine(*, rounds: int, workers: int, local_k: int):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b", choices=list_archs())
     ap.add_argument("--batch", type=int, default=4)

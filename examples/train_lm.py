@@ -24,6 +24,7 @@ import jax
 
 from repro.configs import get_config, smoke_config
 from repro.core.adaseg import AdaSEGConfig
+from repro.launch.cache import enable_compile_cache
 from repro.launch.train import TrainPlan, make_ps_engine
 from repro.ps import LognormalLatency, StochasticQuantizeCompressor
 
@@ -51,6 +52,7 @@ def build_config(args):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", default="20m", choices=sorted(PRESETS))
     ap.add_argument("--arch", default=None, help="use a zoo architecture")

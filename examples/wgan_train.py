@@ -22,11 +22,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import AdaSEGConfig
+from repro.launch.cache import enable_compile_cache
 from repro.problems import make_wgan_problem
 from repro.ps import ModelWorker, PSConfig, PSEngine, StochasticQuantizeCompressor
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--workers", type=int, default=4)
     ap.add_argument("--k-local", type=int, default=20)

@@ -17,5 +17,7 @@
   inter-chunk recurrence over summary states).
 
 Each kernel ships ``kernel.py`` (pl.pallas_call + BlockSpec), ``ops.py``
-(jit'd wrapper, CPU interpret fallback) and ``ref.py`` (pure-jnp oracle).
+(jit'd wrapper; interpret mode on the CPU only) and ``ref.py`` (pure-jnp
+oracle). ``tiling.py`` holds the (8, 128) slab layout and VMEM block
+sizing the elementwise kernels share.
 """

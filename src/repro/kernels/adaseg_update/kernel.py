@@ -29,11 +29,12 @@ per-block partial squared norms (``want_norm=True`` / :func:`adaseg_raw`),
 the caller folds the partials into the scale min(1, r/‖·‖), and pass 2
 (:func:`adaseg_finish`) applies the scale while accumulating (Z_t)².
 
-Layout: parameters are flattened and tiled as (num_blocks, block); grid is
-1-D over blocks; scalars arrive as SMEM tiles; per-block partial reductions
-land in (num_blocks, ·) SMEM outputs reduced by the caller. Partial sums
-mask the zero-padding of the last block so a box with lo > 0 cannot leak
-clip(0) into the statistic.
+Layout (:mod:`..tiling`): a flat leaf is a lane-dense (rows, 128) slab,
+the grid is 1-D over VMEM-sized (br, 128) blocks, the η schedule arrives
+as an SMEM scalar, and each reduction leaves one (8, 128) f32 tile of
+partials per block, summed by the caller. Partial sums mask everything
+past the leaf, so neither the zero padding nor a ragged last block can
+leak into a statistic (e.g. clip(0) under a box with lo > 0).
 """
 from __future__ import annotations
 
@@ -42,7 +43,20 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from ..tiling import (
+    LANES,
+    SUBLANES,
+    block_rows,
+    fold,
+    from_slab,
+    needs_mask,
+    num_rows,
+    scalar,
+    smem,
+    to_slab,
+    valid,
+)
 
 
 def _resolve_eta(sched_ref, *, fuse_eta, g0_sq, d_alpha):
@@ -54,36 +68,27 @@ def _resolve_eta(sched_ref, *, fuse_eta, g0_sq, d_alpha):
     return s
 
 
-def _pad_mask(n, block):
-    """(1, block) validity mask for the current grid block (pad rows are
-    zero-filled; only the statistic reductions need masking)."""
-    i = pl.program_id(0)
-    idx = i * block + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
-    return idx < n
-
-
 def _sched_arg(eta, sum_sq):
     """(SMEM scalar, fuse_eta flag) from the mutually-exclusive η inputs."""
     if (eta is None) == (sum_sq is None):
         raise ValueError("pass exactly one of eta= or sum_sq=")
     if sum_sq is not None:
-        return jnp.asarray(sum_sq, jnp.float32).reshape(1, 1), True
-    return jnp.asarray(eta, jnp.float32).reshape(1, 1), False
+        return scalar(sum_sq), True
+    return scalar(eta), False
 
 
-def _tile(x, block):
-    (n,) = x.shape
-    pad = (-n) % block
-    if pad:
-        x = jnp.pad(x, (0, pad))
-    return x.reshape((n + pad) // block, block)
+def _partial(x, *, n, br, mask):
+    """This block's (8, 128) partial sums of ``x``, masked to the leaf."""
+    if mask:
+        x = jnp.where(valid(n, br, pl.program_id(0)), x, 0.0)
+    return fold(x)
 
 
 # ---------------------------------------------------------------------------
-# Kernel bodies.
+# Kernel bodies. ``tile`` is functools.partial(_partial, n=, br=, mask=).
 # ---------------------------------------------------------------------------
 
-def _explore_kernel(sched_ref, z_ref, m_ref, out_ref, acc_ref, *,
+def _explore_kernel(sched_ref, z_ref, m_ref, out_ref, *acc_refs, tile,
                     lo, hi, fuse_eta, g0_sq, d_alpha, want_norm):
     eta = _resolve_eta(sched_ref, fuse_eta=fuse_eta, g0_sq=g0_sq,
                        d_alpha=d_alpha)
@@ -93,13 +98,13 @@ def _explore_kernel(sched_ref, z_ref, m_ref, out_ref, acc_ref, *,
     if lo is not None:
         out = jnp.clip(out, lo, hi)
     out_ref[...] = out.astype(out_ref.dtype)
-    # Raw/l2 pass 1: partial ‖out‖² (pad contributes exact zeros).
-    acc_ref[0, 0] = jnp.sum(out * out) if want_norm else jnp.float32(0.0)
-    acc_ref[0, 1] = jnp.sum(m * m)                     # fused ‖M_t‖² partial
+    acc_refs[0][...] = tile(m * m)                     # fused ‖M_t‖² partial
+    if want_norm:
+        acc_refs[1][...] = tile(out * out)             # raw/l2 pass 1: ‖out‖²
 
 
-def _anchor_kernel(sched_ref, z_ref, zt_ref, g_ref, ztl_ref, acc_ref, *,
-                   lo, hi, fuse_eta, g0_sq, d_alpha, n, block):
+def _anchor_kernel(sched_ref, z_ref, zt_ref, g_ref, ztl_ref, stat_ref,
+                   gsq_ref, *, tile, lo, hi, fuse_eta, g0_sq, d_alpha):
     eta = _resolve_eta(sched_ref, fuse_eta=fuse_eta, g0_sq=g0_sq,
                        d_alpha=d_alpha)
     z = z_ref[...].astype(jnp.float32)
@@ -111,13 +116,12 @@ def _anchor_kernel(sched_ref, z_ref, zt_ref, g_ref, ztl_ref, acc_ref, *,
     ztl_ref[...] = ztl.astype(ztl_ref.dtype)
     d1 = zt - z
     d2 = zt - ztl
-    stat = jnp.where(_pad_mask(n, block), d1 * d1 + d2 * d2, 0.0)
-    acc_ref[0, 0] = jnp.sum(stat)
-    acc_ref[0, 1] = jnp.sum(g * g)                     # fused ‖g_t‖² partial
+    stat_ref[...] = tile(d1 * d1 + d2 * d2)
+    gsq_ref[...] = tile(g * g)                         # fused ‖g_t‖² partial
 
 
 def _finish_kernel(scales_ref, z_ref, zt_raw_ref, ztl_raw_ref,
-                   zt_ref, ztl_ref, acc_ref, *, n, block):
+                   zt_ref, ztl_ref, stat_ref, *, tile):
     s_t = scales_ref[0, 0]
     s_l = scales_ref[0, 1]
     z = z_ref[...].astype(jnp.float32)
@@ -127,12 +131,12 @@ def _finish_kernel(scales_ref, z_ref, zt_raw_ref, ztl_raw_ref,
     ztl_ref[...] = ztl.astype(ztl_ref.dtype)
     d1 = zt - z
     d2 = zt - ztl
-    stat = jnp.where(_pad_mask(n, block), d1 * d1 + d2 * d2, 0.0)
-    acc_ref[0, 0] = jnp.sum(stat)
+    stat_ref[...] = tile(d1 * d1 + d2 * d2)
 
 
-def _update_kernel(sched_ref, z_ref, m_ref, g_ref, zt_ref, ztl_ref, acc_ref,
-                   *, lo, hi, fuse_eta, g0_sq, d_alpha, raw_norms, n, block):
+def _update_kernel(sched_ref, z_ref, m_ref, g_ref, zt_ref, ztl_ref,
+                   *acc_refs, tile, lo, hi, fuse_eta, g0_sq, d_alpha,
+                   raw_norms):
     eta = _resolve_eta(sched_ref, fuse_eta=fuse_eta, g0_sq=g0_sq,
                        d_alpha=d_alpha)
     z = z_ref[...].astype(jnp.float32)
@@ -142,8 +146,8 @@ def _update_kernel(sched_ref, z_ref, m_ref, g_ref, zt_ref, ztl_ref, acc_ref,
         # l2 pass 1: write raw candidates, reduce their squared norms.
         zt_ref[...] = z_t.astype(zt_ref.dtype)
         ztl_ref[...] = z_tl.astype(ztl_ref.dtype)
-        acc_ref[0, 0] = jnp.sum(z_t * z_t)
-        acc_ref[0, 1] = jnp.sum(z_tl * z_tl)
+        acc_refs[0][...] = tile(z_t * z_t)
+        acc_refs[1][...] = tile(z_tl * z_tl)
         return
     if lo is not None:
         z_t = jnp.clip(z_t, lo, hi)
@@ -152,121 +156,99 @@ def _update_kernel(sched_ref, z_ref, m_ref, g_ref, zt_ref, ztl_ref, acc_ref,
     ztl_ref[...] = z_tl.astype(ztl_ref.dtype)
     d1 = z_t - z
     d2 = z_t - z_tl
-    stat = jnp.where(_pad_mask(n, block), d1 * d1 + d2 * d2, 0.0)
-    acc_ref[0, 0] = jnp.sum(stat)
-    acc_ref[0, 1] = jnp.float32(0.0)
+    acc_refs[0][...] = tile(d1 * d1 + d2 * d2)
 
 
 # ---------------------------------------------------------------------------
 # Per-leaf entry points (flat 1-D vectors; pytree composition in ops.py).
 # ---------------------------------------------------------------------------
 
-def _scalar_spec():
-    return pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM)
-
-
-def _vec_spec(block):
-    return pl.BlockSpec((1, block), lambda i: (i, 0))
-
-
-def _acc_spec(width):
-    return pl.BlockSpec((1, width), lambda i: (i, 0), memory_space=pltpu.SMEM)
+def _leaf_call(kernel, scalars, vectors, n_out, n_acc, *, block, interpret,
+               **static):
+    """Shared pallas_call plumbing: ``scalars`` go to SMEM, the flat
+    ``vectors`` stream as (br, 128) blocks into ``n_out`` leaf-shaped
+    outputs and ``n_acc`` partial-sum tiles. Returns the outputs as flat
+    leaves followed by the summed reductions."""
+    (n,) = vectors[0].shape
+    rows = num_rows(n)
+    br = block_rows(rows, len(vectors) + n_out, block)
+    nb = pl.cdiv(rows, br)
+    vec = pl.BlockSpec((br, LANES), lambda i: (i, 0))
+    acc = pl.BlockSpec((SUBLANES, LANES), lambda i: (i, 0))
+    tile = functools.partial(_partial, n=n, br=br,
+                             mask=needs_mask(n, rows, br))
+    outs = pl.pallas_call(
+        functools.partial(kernel, tile=tile, **static),
+        grid=(nb,),
+        in_specs=[smem()] * len(scalars) + [vec] * len(vectors),
+        out_specs=[vec] * n_out + [acc] * n_acc,
+        out_shape=(
+            [jax.ShapeDtypeStruct((rows, LANES), vectors[0].dtype)] * n_out
+            + [jax.ShapeDtypeStruct((nb * SUBLANES, LANES), jnp.float32)]
+            * n_acc
+        ),
+        interpret=interpret,
+    )(*scalars, *(to_slab(v, rows) for v in vectors))
+    return ([from_slab(o, n) for o in outs[:n_out]]
+            + [jnp.sum(a) for a in outs[n_out:]])
 
 
 def adaseg_explore(z_star, m_t, eta=None, *, sum_sq=None, g0=0.0,
                    d_alpha=1.0, lo=None, hi=None, want_norm=False,
-                   block: int = 4096, interpret: bool = False):
+                   block: int | None = None, interpret: bool = False):
     """Exploration half-step z_t = Π_box(z* − η·m_t) on a flat leaf.
 
     Returns ``(z_t, norm_partial, msq_partial)`` — ``norm_partial`` is
     ‖z_t‖² when ``want_norm`` (the l2 two-pass raw mode; pass ``lo=None``),
-    else 0; ``msq_partial`` is the fused ‖m_t‖² reduction.
+    else 0; ``msq_partial`` is the fused ‖m_t‖² reduction. ``block`` caps
+    the elements per grid step (default: sized to VMEM).
     """
-    (n,) = z_star.shape
     sched, fuse = _sched_arg(eta, sum_sq)
-    nb = (n + (-n) % block) // block
-    kernel = functools.partial(
-        _explore_kernel, lo=lo, hi=hi, fuse_eta=fuse, g0_sq=g0 ** 2,
-        d_alpha=d_alpha, want_norm=want_norm,
+    outs = _leaf_call(
+        _explore_kernel, [sched], [z_star, m_t], 1, 1 + want_norm,
+        block=block, interpret=interpret, lo=lo, hi=hi, fuse_eta=fuse,
+        g0_sq=g0 ** 2, d_alpha=d_alpha, want_norm=want_norm,
     )
-    out, acc = pl.pallas_call(
-        kernel,
-        grid=(nb,),
-        in_specs=[_scalar_spec(), _vec_spec(block), _vec_spec(block)],
-        out_specs=[_vec_spec(block), _acc_spec(2)],
-        out_shape=[
-            jax.ShapeDtypeStruct((nb, block), z_star.dtype),
-            jax.ShapeDtypeStruct((nb, 2), jnp.float32),
-        ],
-        interpret=interpret,
-    )(sched, _tile(z_star, block), _tile(m_t, block))
-    return out.reshape(-1)[:n], jnp.sum(acc[:, 0]), jnp.sum(acc[:, 1])
+    norm = outs[2] if want_norm else jnp.float32(0.0)
+    return outs[0], norm, outs[1]
 
 
 def adaseg_anchor(z_star, z_t, g_t, eta=None, *, sum_sq=None, g0=0.0,
-                  d_alpha=1.0, lo=None, hi=None, block: int = 4096,
+                  d_alpha=1.0, lo=None, hi=None, block: int | None = None,
                   interpret: bool = False):
     """Anchor half-step z̃ = Π_box(z* − η·g_t) given the materialized z_t.
 
     Returns ``(z_tilde, stat_partial, gsq_partial)`` with
     ``stat_partial = ‖z_t − z*‖² + ‖z_t − z̃‖²`` (caller divides by 5η²).
     """
-    (n,) = z_star.shape
     sched, fuse = _sched_arg(eta, sum_sq)
-    nb = (n + (-n) % block) // block
-    kernel = functools.partial(
-        _anchor_kernel, lo=lo, hi=hi, fuse_eta=fuse, g0_sq=g0 ** 2,
-        d_alpha=d_alpha, n=n, block=block,
+    ztl, stat, gsq = _leaf_call(
+        _anchor_kernel, [sched], [z_star, z_t, g_t], 1, 2, block=block,
+        interpret=interpret, lo=lo, hi=hi, fuse_eta=fuse, g0_sq=g0 ** 2,
+        d_alpha=d_alpha,
     )
-    ztl, acc = pl.pallas_call(
-        kernel,
-        grid=(nb,),
-        in_specs=[_scalar_spec(), _vec_spec(block), _vec_spec(block),
-                  _vec_spec(block)],
-        out_specs=[_vec_spec(block), _acc_spec(2)],
-        out_shape=[
-            jax.ShapeDtypeStruct((nb, block), z_star.dtype),
-            jax.ShapeDtypeStruct((nb, 2), jnp.float32),
-        ],
-        interpret=interpret,
-    )(sched, _tile(z_star, block), _tile(z_t, block), _tile(g_t, block))
-    return ztl.reshape(-1)[:n], jnp.sum(acc[:, 0]), jnp.sum(acc[:, 1])
+    return ztl, stat, gsq
 
 
 def adaseg_finish(z_star, zt_raw, ztl_raw, scale_t, scale_tl, *,
-                  block: int = 4096, interpret: bool = False):
+                  block: int | None = None, interpret: bool = False):
     """l2 pass 2: scale raw candidates onto the ball, fuse the (Z_t)² stat.
 
     Returns ``(z_t, z_tilde, stat_partial)``.
     """
-    (n,) = z_star.shape
-    nb = (n + (-n) % block) // block
     scales = jnp.stack([
         jnp.asarray(scale_t, jnp.float32), jnp.asarray(scale_tl, jnp.float32)
     ]).reshape(1, 2)
-    kernel = functools.partial(_finish_kernel, n=n, block=block)
-    z_t, z_tl, acc = pl.pallas_call(
-        kernel,
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((1, 2), lambda i: (0, 0), memory_space=pltpu.SMEM),
-            _vec_spec(block), _vec_spec(block), _vec_spec(block),
-        ],
-        out_specs=[_vec_spec(block), _vec_spec(block), _acc_spec(1)],
-        out_shape=[
-            jax.ShapeDtypeStruct((nb, block), z_star.dtype),
-            jax.ShapeDtypeStruct((nb, block), z_star.dtype),
-            jax.ShapeDtypeStruct((nb, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(scales, _tile(z_star, block), _tile(zt_raw, block),
-      _tile(ztl_raw, block))
-    return z_t.reshape(-1)[:n], z_tl.reshape(-1)[:n], jnp.sum(acc)
+    z_t, z_tl, stat = _leaf_call(
+        _finish_kernel, [scales], [z_star, zt_raw, ztl_raw], 2, 1,
+        block=block, interpret=interpret,
+    )
+    return z_t, z_tl, stat
 
 
 def adaseg_update(
     z_star, m_t, g_t, eta=None, *, sum_sq=None, g0=0.0, d_alpha=1.0,
-    lo=None, hi=None, raw_norms: bool = False, block: int = 4096,
+    lo=None, hi=None, raw_norms: bool = False, block: int | None = None,
     interpret: bool = False,
 ):
     """One-shot fused EG double update on a flat leaf (both oracles known).
@@ -276,28 +258,12 @@ def adaseg_update(
     two-pass raw mode: no projection, and the partials are
     ``(‖z_t‖², ‖z̃‖²)`` for the caller's ball-scale computation.
     """
-    (n,) = z_star.shape
     sched, fuse = _sched_arg(eta, sum_sq)
-    nb = (n + (-n) % block) // block
-    kernel = functools.partial(
-        _update_kernel, lo=lo, hi=hi, fuse_eta=fuse, g0_sq=g0 ** 2,
-        d_alpha=d_alpha, raw_norms=raw_norms, n=n, block=block,
+    outs = _leaf_call(
+        _update_kernel, [sched], [z_star, m_t, g_t], 2, 1 + raw_norms,
+        block=block, interpret=interpret, lo=lo, hi=hi, fuse_eta=fuse,
+        g0_sq=g0 ** 2, d_alpha=d_alpha, raw_norms=raw_norms,
     )
-    z_t, z_tl, acc = pl.pallas_call(
-        kernel,
-        grid=(nb,),
-        in_specs=[_scalar_spec(), _vec_spec(block), _vec_spec(block),
-                  _vec_spec(block)],
-        out_specs=[_vec_spec(block), _vec_spec(block), _acc_spec(2)],
-        out_shape=[
-            jax.ShapeDtypeStruct((nb, block), z_star.dtype),
-            jax.ShapeDtypeStruct((nb, block), z_star.dtype),
-            jax.ShapeDtypeStruct((nb, 2), jnp.float32),
-        ],
-        interpret=interpret,
-    )(sched, _tile(z_star, block), _tile(m_t, block), _tile(g_t, block))
-    z_t = z_t.reshape(-1)[:n]
-    z_tl = z_tl.reshape(-1)[:n]
     if raw_norms:
-        return z_t, z_tl, (jnp.sum(acc[:, 0]), jnp.sum(acc[:, 1]))
-    return z_t, z_tl, jnp.sum(acc[:, 0])
+        return outs[0], outs[1], (outs[2], outs[3])
+    return outs[0], outs[1], outs[2]

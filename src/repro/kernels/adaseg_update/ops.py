@@ -5,8 +5,8 @@ These are the functions the optimizer actually calls
 :func:`adaseg_tree_explore` + :func:`adaseg_tree_anchor`; benchmarks and
 parity tests use the one-shot :func:`adaseg_tree_update`). They fall back to
 interpret mode automatically off-TPU so the same call site works in CPU
-tests and on real hardware, and to pure-jnp references with
-``use_kernel=False``.
+tests and on real hardware (:func:`..tiling.interpret_mode`: CPU only),
+and to pure-jnp references with ``use_kernel=False``.
 
 Projections are passed as a static *spec* rather than a callable so the
 kernel can fuse them without a semantics fork:
@@ -47,6 +47,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..tiling import interpret_mode
 from .kernel import (
     adaseg_anchor,
     adaseg_explore,
@@ -61,30 +62,9 @@ from .ref import (
 )
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _leaf_block(block, n, interp):
-    """Effective block for one flat leaf of size n.
-
-    In interpret mode (off-TPU) the grid is a traced Python loop and VMEM
-    limits don't apply, so one block per leaf keeps the kernel a single
-    fused sweep; on hardware the requested (VMEM-sized) block stands.
-    """
-    return max(n, 1) if interp else block
-
-
 def _kernel_kwargs(use_kernel, block, interp):
-    """Per-leaf kernel kwargs factory shared by the tree wrappers."""
-
-    def kkw(z):
-        if not use_kernel:
-            return {}
-        return dict(block=_leaf_block(block, z.size, interp),
-                    interpret=interp)
-
-    return kkw
+    """Per-leaf kernel kwargs shared by the tree wrappers."""
+    return dict(block=block, interpret=interp) if use_kernel else {}
 
 
 def _norm_proj(proj, lo, hi):
@@ -133,7 +113,7 @@ _STATIC = ("g0", "d_alpha", "proj", "lo", "hi", "use_kernel", "block")
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def adaseg_tree_update(z_star, m_t, g_t, eta=None, *, sum_sq=None,
                        g0=0.0, d_alpha=1.0, proj=None, lo=None, hi=None,
-                       use_kernel=True, block=4096):
+                       use_kernel=True, block=None):
     """Apply the fused EG double update leaf-wise over a parameter pytree.
 
     Returns (z_t_tree, z_tilde_tree, z_sq) with
@@ -142,7 +122,7 @@ def adaseg_tree_update(z_star, m_t, g_t, eta=None, *, sum_sq=None,
     spec = _norm_proj(proj, lo, hi)
     leaves_z, treedef = jax.tree.flatten(z_star)
     leaves_z, leaves_m, leaves_g = _flatten_with(treedef, leaves_z, m_t, g_t)
-    interp = not _on_tpu()
+    interp = interpret_mode() if use_kernel else False
     eta_val = _eta_value(eta, sum_sq, g0, d_alpha)
     kw = dict(eta=eta, sum_sq=sum_sq, g0=g0, d_alpha=d_alpha)
 
@@ -154,7 +134,7 @@ def adaseg_tree_update(z_star, m_t, g_t, eta=None, *, sum_sq=None,
             if use_kernel:
                 z_t, z_tl, part = adaseg_update(
                     z.reshape(-1), m.reshape(-1), g.reshape(-1),
-                    lo=blo, hi=bhi, block=_leaf_block(block, z.size, interp),
+                    lo=blo, hi=bhi, block=block,
                     interpret=interp, **kw,
                 )
                 z_t, z_tl = z_t.reshape(shape), z_tl.reshape(shape)
@@ -173,7 +153,7 @@ def adaseg_tree_update(z_star, m_t, g_t, eta=None, *, sum_sq=None,
             if use_kernel:
                 zt_raw, ztl_raw, (nt, nl) = adaseg_update(
                     z.reshape(-1), m.reshape(-1), g.reshape(-1),
-                    raw_norms=True, block=_leaf_block(block, z.size, interp),
+                    raw_norms=True, block=block,
                     interpret=interp, **kw,
                 )
             else:
@@ -193,7 +173,7 @@ def adaseg_tree_update(z_star, m_t, g_t, eta=None, *, sum_sq=None,
             if use_kernel:
                 z_t, z_tl, part = adaseg_finish(
                     z.reshape(-1), zt_raw, ztl_raw, s_t, s_l,
-                    block=_leaf_block(block, z.size, interp),
+                    block=block,
                     interpret=interp,
                 )
             else:
@@ -212,7 +192,7 @@ def adaseg_tree_update(z_star, m_t, g_t, eta=None, *, sum_sq=None,
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def adaseg_tree_explore(z_star, m_t, eta=None, *, sum_sq=None, g0=0.0,
                         d_alpha=1.0, proj=None, lo=None, hi=None,
-                        use_kernel=True, block=4096):
+                        use_kernel=True, block=None):
     """Exploration half-step z_t = Π(z* − η·M_t) over a pytree.
 
     Returns ``(z_t_tree, m_sq)`` where m_sq = Σ‖M_t‖² (fused into the same
@@ -221,7 +201,7 @@ def adaseg_tree_explore(z_star, m_t, eta=None, *, sum_sq=None, g0=0.0,
     spec = _norm_proj(proj, lo, hi)
     leaves_z, treedef = jax.tree.flatten(z_star)
     leaves_z, leaves_m = _flatten_with(treedef, leaves_z, m_t)
-    interp = not _on_tpu()
+    interp = interpret_mode() if use_kernel else False
     kw = dict(eta=eta, sum_sq=sum_sq, g0=g0, d_alpha=d_alpha)
     fn = adaseg_explore if use_kernel else adaseg_explore_ref
     kkw = _kernel_kwargs(use_kernel, block, interp)
@@ -232,7 +212,7 @@ def adaseg_tree_explore(z_star, m_t, eta=None, *, sum_sq=None, g0=0.0,
         for z, m in zip(leaves_z, leaves_m):
             shape = z.shape
             out, _, msq = fn(z.reshape(-1), m.reshape(-1), lo=blo, hi=bhi,
-                             **kw, **kkw(z))
+                             **kw, **kkw)
             outs.append(out.reshape(shape))
             msqs.append(msq)
         return treedef.unflatten(outs), sum(msqs)
@@ -241,7 +221,7 @@ def adaseg_tree_explore(z_star, m_t, eta=None, *, sum_sq=None, g0=0.0,
     raws, norms, msqs = [], [], []
     for z, m in zip(leaves_z, leaves_m):
         out, nrm, msq = fn(z.reshape(-1), m.reshape(-1), want_norm=True,
-                           **kw, **kkw(z))
+                           **kw, **kkw)
         raws.append(out)
         norms.append(nrm)
         msqs.append(msq)
@@ -256,7 +236,7 @@ def adaseg_tree_explore(z_star, m_t, eta=None, *, sum_sq=None, g0=0.0,
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def adaseg_tree_anchor(z_star, z_t, g_t, eta=None, *, sum_sq=None, g0=0.0,
                        d_alpha=1.0, proj=None, lo=None, hi=None,
-                       use_kernel=True, block=4096):
+                       use_kernel=True, block=None):
     """Anchor half-step z̃ = Π(z* − η·g_t) over a pytree, given z_t.
 
     Returns ``(z_tilde_tree, stat, g_sq)`` with
@@ -266,7 +246,7 @@ def adaseg_tree_anchor(z_star, z_t, g_t, eta=None, *, sum_sq=None, g0=0.0,
     spec = _norm_proj(proj, lo, hi)
     leaves_z, treedef = jax.tree.flatten(z_star)
     leaves_z, leaves_t, leaves_g = _flatten_with(treedef, leaves_z, z_t, g_t)
-    interp = not _on_tpu()
+    interp = interpret_mode() if use_kernel else False
     kw = dict(eta=eta, sum_sq=sum_sq, g0=g0, d_alpha=d_alpha)
 
     if spec[0] != "l2":
@@ -277,7 +257,7 @@ def adaseg_tree_anchor(z_star, z_t, g_t, eta=None, *, sum_sq=None, g0=0.0,
             if use_kernel:
                 ztl, stat, gsq = adaseg_anchor(
                     z.reshape(-1), zt.reshape(-1), g.reshape(-1),
-                    lo=blo, hi=bhi, block=_leaf_block(block, z.size, interp),
+                    lo=blo, hi=bhi, block=block,
                     interpret=interp, **kw,
                 )
                 ztl = ztl.reshape(shape)
@@ -297,7 +277,7 @@ def adaseg_tree_anchor(z_star, z_t, g_t, eta=None, *, sum_sq=None, g0=0.0,
     raws, norms, gsqs = [], [], []
     for z, g in zip(leaves_z, leaves_g):
         raw, nrm, gsq = fn(z.reshape(-1), g.reshape(-1), want_norm=True,
-                           **kw, **kkw(z))
+                           **kw, **kkw)
         raws.append(raw)
         norms.append(nrm)
         gsqs.append(gsq)
@@ -309,7 +289,7 @@ def adaseg_tree_anchor(z_star, z_t, g_t, eta=None, *, sum_sq=None, g0=0.0,
         if use_kernel:
             _, ztl, stat = adaseg_finish(
                 z.reshape(-1), zt.reshape(-1), raw, 1.0, s_l,
-                block=_leaf_block(block, z.size, interp), interpret=interp,
+                block=block, interpret=interp,
             )
         else:
             _, ztl, stat = adaseg_finish_ref(

@@ -1,4 +1,4 @@
-"""Jit'd wrapper for the flash-attention kernel with CPU interpret fallback
+"""Jit'd wrapper for the flash-attention kernel (interpreted on the CPU)
 and automatic sequence padding to the block size.
 
 Examples
@@ -20,12 +20,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..tiling import interpret_mode
 from .kernel import flash_attention
 from .ref import attention_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(
@@ -56,6 +53,6 @@ def attention(q, k, v, *, causal=True, window=None, softcap=None, scale=None,
     assert causal or (pad_q == 0 and pad_k == 0)
     out = flash_attention(
         q, k, v, causal=causal, window=window, softcap=softcap, scale=scale,
-        block_q=bq, block_k=bk, interpret=not _on_tpu(),
+        block_q=bq, block_k=bk, interpret=interpret_mode(),
     )
     return out[:, :, :s, :]

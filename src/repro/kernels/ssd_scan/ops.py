@@ -1,4 +1,4 @@
-"""Jit'd wrapper for the SSD scan kernel with CPU interpret fallback.
+"""Jit'd wrapper for the SSD scan kernel (interpreted on the CPU).
 Examples
 --------
 The chunked SSD form agrees with the sequential scan reference:
@@ -22,16 +22,13 @@ import functools
 
 import jax
 
+from ..tiling import interpret_mode
 from .kernel import ssd_scan
 from .ref import ssd_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "use_kernel"))
 def ssd(x, dt, a, b, c, *, chunk=128, use_kernel=True):
     if not use_kernel:
         return ssd_ref(x, dt, a, b, c)
-    return ssd_scan(x, dt, a, b, c, chunk=chunk, interpret=not _on_tpu())
+    return ssd_scan(x, dt, a, b, c, chunk=chunk, interpret=interpret_mode())

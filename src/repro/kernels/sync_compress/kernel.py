@@ -24,12 +24,14 @@ each HBM pass does all the work available at that point:
   (optionally over survivors), weighted sum over the worker axis and the
   broadcast back, one read + one write of the stacked fleet payload.
 
-Layout mirrors ``kernels.adaseg_update``: leaves arrive worker-stacked and
-flattened as ``(M, n)``, tiled to ``(M, nb·block)``; uplink kernels run on a
-``(M, nb)`` grid with per-worker scalars (weight, scale, aliveness, seed) in
-SMEM; the merge runs on a ``(nb,)`` grid over full-fleet ``(M, block)``
-tiles. Dead workers (``alive = 0``) send exact zeros and keep their residual
-frozen — the engines' fault semantics, fused.
+Layout (:mod:`..tiling`): leaves arrive worker-stacked and flattened as
+``(M, n)`` and stream as ``(M, rows, 128)`` slabs. The uplink kernels run
+on an ``(M, nb)`` grid of ``(1, br, 128)`` blocks and read their
+per-worker scalars (weight, scale, aliveness, seed words) one element at a
+time from whole ``(M,)`` SMEM vectors; the merges run on an ``(nb,)`` grid
+over full-fleet ``(M, br, 128)`` blocks. Reductions leave one (8, 128)
+tile of partials per block. Dead workers (``alive = 0``) send exact zeros
+and keep their residual frozen — the engines' fault semantics, fused.
 """
 from __future__ import annotations
 
@@ -38,59 +40,51 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
+from ..tiling import (
+    LANES,
+    SUBLANES,
+    block_rows,
+    fold,
+    from_slab,
+    needs_mask,
+    num_rows,
+    smem,
+    to_slab,
+    valid,
+)
 from .ref import bits_to_uniform, threefry2x32
-
-
-def _tile_rows(x, block):
-    """Pad a stacked (M, n) leaf to (M, nb·block)."""
-    m, n = x.shape
-    pad = (-n) % block
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad)))
-    return x
-
-
-def _scalar_spec():
-    return pl.BlockSpec((1, 1), lambda i, j: (i, 0), memory_space=pltpu.SMEM)
-
-
-def _seed_spec():
-    return pl.BlockSpec((1, 2), lambda i, j: (i, 0), memory_space=pltpu.SMEM)
-
-
-def _row_spec(block):
-    return pl.BlockSpec((1, block), lambda i, j: (i, j))
-
-
-def _acc_spec():
-    return pl.BlockSpec((1, 1), lambda i, j: (i, j), memory_space=pltpu.SMEM)
 
 
 def _eff_tile(z_ref, ef_ref, w_ref, *, has_w, has_ef):
     """The codec's effective message for the current (worker, block) tile:
     ``w·z (+ ef)`` — computed in-register, never written to HBM unless the
     kernel's job IS to write it."""
-    eff = z_ref[...].astype(jnp.float32)
+    eff = z_ref[0].astype(jnp.float32)
     if has_w:
-        eff = w_ref[0, 0] * eff
+        eff = w_ref[pl.program_id(0)] * eff
     if has_ef:
-        eff = eff + ef_ref[...].astype(jnp.float32)
+        eff = eff + ef_ref[0].astype(jnp.float32)
     return eff
 
 
-def _kernel_uniform(seed_ref, block):
+def _kernel_uniform(seed_ref, br):
     """The shared uniform stream for this tile's global element indices:
     threefry2x32 bits generated in-kernel, same derivation as
     :func:`.ref.threefry_uniform`."""
-    j = pl.program_id(1)
-    idx = (j * block
-           + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1))
+    i = pl.program_id(0)
+    idx = (pl.program_id(1) * br
+           + jax.lax.broadcasted_iota(jnp.int32, (br, LANES), 0)) * LANES
+    idx = (idx + jax.lax.broadcasted_iota(jnp.int32, (br, LANES), 1))
     idx = idx.astype(jnp.uint32)
-    bits, _ = threefry2x32(seed_ref[0, 0], seed_ref[0, 1],
+    bits, _ = threefry2x32(seed_ref[2 * i], seed_ref[2 * i + 1],
                            idx, jnp.zeros_like(idx))
     return bits_to_uniform(bits)
+
+
+def _flag(ref, k, like):
+    """Scalar ``ref[k] > 0`` broadcast to a vector mask shaped ``like``."""
+    return jnp.full(like.shape, ref[k], jnp.float32) > 0.0
 
 
 # ---------------------------------------------------------------------------
@@ -100,19 +94,21 @@ def _kernel_uniform(seed_ref, block):
 # ef, mask), then outputs (sent/eff, ef_out / acc).
 # ---------------------------------------------------------------------------
 
-def _stats_kernel(*refs, has_w, has_ef):
+def _stats_kernel(*refs, has_w, has_ef, n, br, mask):
     it = iter(refs)
     w_ref = next(it) if has_w else None
     z_ref = next(it)
     ef_ref = next(it) if has_ef else None
     acc_ref = next(it)
-    eff = _eff_tile(z_ref, ef_ref, w_ref, has_w=has_w, has_ef=has_ef)
-    # pad lanes are zero-filled → |eff| = 0 there, which cannot win the max
-    # (the caller clamps the folded scale to ≥ 1e-30 anyway).
-    acc_ref[0, 0] = jnp.max(jnp.abs(eff))
+    mag = jnp.abs(_eff_tile(z_ref, ef_ref, w_ref, has_w=has_w,
+                            has_ef=has_ef))
+    if mask:
+        # 0 cannot win the max (the caller clamps the scale to ≥ 1e-30)
+        mag = jnp.where(valid(n, br, pl.program_id(1)), mag, 0.0)
+    acc_ref[0] = fold(mag, "max")
 
 
-def _quantize_kernel(*refs, levels, block, has_w, has_ef, has_alive):
+def _quantize_kernel(*refs, levels, has_w, has_ef, has_alive, br, **_):
     it = iter(refs)
     w_ref = next(it) if has_w else None
     scale_ref = next(it)
@@ -124,35 +120,35 @@ def _quantize_kernel(*refs, levels, block, has_w, has_ef, has_alive):
     ef_out_ref = next(it) if has_ef else None
 
     eff = _eff_tile(z_ref, ef_ref, w_ref, has_w=has_w, has_ef=has_ef)
-    scale = scale_ref[0, 0]
+    scale = scale_ref[pl.program_id(0)]
     y = jnp.abs(eff) / scale * levels
     lo = jnp.floor(y)
-    up = _kernel_uniform(seed_ref, block) < (y - lo)
+    up = _kernel_uniform(seed_ref, br) < (y - lo)
     mag = (lo + up.astype(eff.dtype)) * (scale / levels)
     sent = jnp.sign(eff) * mag
     ef_new = eff - sent
     if has_alive:
-        ok = alive_ref[0, 0] > 0.0
+        ok = _flag(alive_ref, pl.program_id(0), eff)
         sent = jnp.where(ok, sent, jnp.zeros_like(sent))
         if has_ef:
             ef_new = jnp.where(ok, eff - sent,
-                               ef_ref[...].astype(jnp.float32))
-    sent_ref[...] = sent.astype(sent_ref.dtype)
+                               ef_ref[0].astype(jnp.float32))
+    sent_ref[0] = sent.astype(sent_ref.dtype)
     if has_ef:
-        ef_out_ref[...] = ef_new.astype(ef_out_ref.dtype)
+        ef_out_ref[0] = ef_new.astype(ef_out_ref.dtype)
 
 
-def _eff_kernel(*refs, has_w, has_ef):
+def _eff_kernel(*refs, has_w, has_ef, **_):
     it = iter(refs)
     w_ref = next(it) if has_w else None
     z_ref = next(it)
     ef_ref = next(it) if has_ef else None
     out_ref = next(it)
     eff = _eff_tile(z_ref, ef_ref, w_ref, has_w=has_w, has_ef=has_ef)
-    out_ref[...] = eff.astype(out_ref.dtype)
+    out_ref[0] = eff.astype(out_ref.dtype)
 
 
-def _mask_kernel(*refs, has_ef, has_alive):
+def _mask_kernel(*refs, has_ef, has_alive, **_):
     it = iter(refs)
     alive_ref = next(it) if has_alive else None
     eff_ref = next(it)
@@ -161,91 +157,92 @@ def _mask_kernel(*refs, has_ef, has_alive):
     sent_ref = next(it)
     ef_out_ref = next(it) if has_ef else None
 
-    eff = eff_ref[...].astype(jnp.float32)
-    sent = jnp.where(mask_ref[...] != 0, eff, jnp.zeros_like(eff))
+    eff = eff_ref[0].astype(jnp.float32)
+    sent = jnp.where(mask_ref[0] != 0, eff, jnp.zeros_like(eff))
     ef_new = eff - sent
     if has_alive:
-        ok = alive_ref[0, 0] > 0.0
+        ok = _flag(alive_ref, pl.program_id(0), eff)
         sent = jnp.where(ok, sent, jnp.zeros_like(sent))
         if has_ef:
             ef_new = jnp.where(ok, eff - sent,
-                               ef_ref[...].astype(jnp.float32))
-    sent_ref[...] = sent.astype(sent_ref.dtype)
+                               ef_ref[0].astype(jnp.float32))
+    sent_ref[0] = sent.astype(sent_ref.dtype)
     if has_ef:
-        ef_out_ref[...] = ef_new.astype(ef_out_ref.dtype)
+        ef_out_ref[0] = ef_new.astype(ef_out_ref.dtype)
 
 
-def _trimmed_kernel(*refs, m, trim, has_recv):
+def _deliver(out_ref, merged, recv_ref, old_ref, m):
+    """Write the merge to every worker row; with ``recv``, non-receiving
+    rows keep their ``old`` value."""
+    for k in range(m):
+        row = merged
+        if recv_ref is not None:
+            row = jnp.where(_flag(recv_ref, k, merged), merged,
+                            old_ref[k].astype(jnp.float32))
+        out_ref[k] = row.astype(out_ref.dtype)
+
+
+def _trimmed_kernel(*refs, m, has_recv, **_):
     """Robust server merge: per-coordinate β-trimmed weighted mean via
     sort-free streaming rank selection — same expressions as
-    :func:`.ref.trimmed_merge_ref`, on the full-fleet (M, block) tile.
+    :func:`.ref.trimmed_merge_ref`, on the full-fleet (M, br, 128) block.
 
-    The rank accumulation is an unrolled Python loop over the static worker
-    count: each pass broadcasts one row against the whole tile, so the
-    selection stays in-register (no sort network, no gather)."""
+    The rank accumulation is unrolled over the static worker count; the
+    tie-break ``k < i`` is static, so each pair costs one compare and the
+    selection stays in-register (no sort network, no gather). The trim
+    window ``[b, n_incl − 1 − b]`` arrives as two SMEM scalars."""
     it = iter(refs)
     w_ref = next(it)
     incl_ref = next(it)
+    win_ref = next(it)
     recv_ref = next(it) if has_recv else None
     z_ref = next(it)
     old_ref = next(it) if has_recv else None
     out_ref = next(it)
 
-    z = z_ref[...].astype(jnp.float32)                  # (M, block)
-    incl = incl_ref[0, :]                               # (M,) 0/1
-    n_incl = jnp.sum(incl)
-    b = jnp.minimum(jnp.float32(trim), jnp.floor((n_incl - 1.0) * 0.5))
-    row_ids = jax.lax.broadcasted_iota(jnp.int32, z.shape, 0)
-    rank = jnp.zeros_like(z)
-    for k in range(m):                        # streaming: one row per pass
-        zk = z[k:k + 1, :]
-        less = (zk < z) | ((zk == z) & (k < row_ids))
-        rank = rank + incl[k] * less.astype(jnp.float32)
-    keep = ((rank >= b) & (rank <= n_incl - 1.0 - b)
-            & (incl.reshape(m, 1) > 0.0))
-    wk = w_ref[0, :].reshape(m, 1) * keep.astype(jnp.float32)
-    denom = jnp.maximum(jnp.sum(wk, axis=0, keepdims=True), 1e-30)
-    mean = jnp.sum(wk * z, axis=0, keepdims=True) / denom
-    merged = jnp.broadcast_to(mean, z_ref.shape)
-    if has_recv:
-        keep_rows = recv_ref[0, :].reshape(m, 1) > 0.0
-        merged = jnp.where(keep_rows, merged,
-                           old_ref[...].astype(jnp.float32))
-    out_ref[...] = merged.astype(out_ref.dtype)
+    z = [z_ref[k].astype(jnp.float32) for k in range(m)]
+    b, top = win_ref[0], win_ref[1]
+    num = den = None
+    for i in range(m):
+        rank = jnp.zeros_like(z[i])
+        for k in range(m):
+            if k == i:
+                continue
+            less = (z[k] <= z[i]) if k < i else (z[k] < z[i])
+            rank = rank + incl_ref[k] * less.astype(jnp.float32)
+        keep = (rank >= b) & (rank <= top) & _flag(incl_ref, i, rank)
+        wk = w_ref[i] * keep.astype(jnp.float32)
+        num = wk * z[i] if num is None else num + wk * z[i]
+        den = wk if den is None else den + wk
+    mean = num / jnp.maximum(den, 1e-30)
+    _deliver(out_ref, mean, recv_ref, old_ref, m)
 
 
-def _merge_kernel(*refs, m, normalize, has_w, has_recv):
+def _merge_kernel(*refs, m, has_w, has_recv, **_):
     it = iter(refs)
-    w_ref = next(it) if has_w else None
     recv_ref = next(it) if has_recv else None
+    w_ref = next(it) if has_w else None
     z_ref = next(it)
     old_ref = next(it) if has_recv else None
     out_ref = next(it)
 
-    z = z_ref[...].astype(jnp.float32)                  # (M, block)
+    z = z_ref[...].astype(jnp.float32)
     if has_w:
-        w = w_ref[0, :]                                 # (M,)
-        if normalize:
-            w = w / jnp.sum(w)
-        z = w.reshape(m, 1) * z
-    mean = jnp.sum(z, axis=0, keepdims=True)            # (1, block)
-    merged = jnp.broadcast_to(mean, z_ref.shape)
-    if has_recv:
-        keep = recv_ref[0, :].reshape(m, 1) > 0.0
-        merged = jnp.where(keep, merged,
-                           old_ref[...].astype(jnp.float32))
-    out_ref[...] = merged.astype(out_ref.dtype)
+        z = w_ref[...] * z
+    mean = jnp.sum(z, axis=0)
+    _deliver(out_ref, mean, recv_ref, old_ref, m)
 
 
-def _outer_kernel(*refs, spec, slots):
+def _outer_kernel(*refs, spec, slots, n, br, mask, **_):
     """Server outer-optimizer pass: form Δ = merged − z and apply one
     moment update + step of the static ``spec`` policy in-register — one
-    read of (merged, z, moments), one write of (z′, moments′) per tile,
-    the single extra HBM pass the two-level scheme costs. The per-tile
-    ``Σ Δ²`` lands in an SMEM accumulator for the ‖Δ‖ telemetry. Exact
-    expression sequence of :func:`.ref.outer_apply_ref`."""
+    read of (merged, z, moments), one write of (z′, moments′) per block,
+    the single extra HBM pass the two-level scheme costs. Each block's
+    ``Σ Δ²`` partials land in a (8, 128) tile for the ‖Δ‖ telemetry.
+    Exact expression sequence of :func:`.ref.outer_apply_ref`; adam's bias
+    corrections ``1 − β^t`` arrive precomputed as SMEM scalars."""
     it = iter(refs)
-    t_ref = next(it)
+    bias_ref = next(it)
     g_ref = next(it)
     z_ref = next(it)
     mom_refs = [next(it) for _ in range(slots)]
@@ -254,37 +251,37 @@ def _outer_kernel(*refs, spec, slots):
     acc_ref = next(it)
 
     kind = spec[0]
-    g = g_ref[...].astype(jnp.float32)
-    zz = z_ref[...].astype(jnp.float32)
+    g = g_ref[0].astype(jnp.float32)
+    zz = z_ref[0].astype(jnp.float32)
     d = g - zz
     if kind == "momentum":
         _, lr, beta = spec
-        m_new = (jnp.float32(beta) * mom_refs[0][...].astype(jnp.float32)
-                 + d)
+        m_new = jnp.float32(beta) * mom_refs[0][0].astype(jnp.float32) + d
         z_new = zz + jnp.float32(lr) * m_new
         mom_new = (m_new,)
     elif kind == "nesterov":
         _, lr, beta = spec
-        m_new = (jnp.float32(beta) * mom_refs[0][...].astype(jnp.float32)
-                 + d)
+        m_new = jnp.float32(beta) * mom_refs[0][0].astype(jnp.float32) + d
         z_new = zz + jnp.float32(lr) * (d + jnp.float32(beta) * m_new)
         mom_new = (m_new,)
     else:                                               # adam
         _, lr, b1, b2, eps = spec
-        t_new = t_ref[0, 0] + 1.0
-        m_new = (jnp.float32(b1) * mom_refs[0][...].astype(jnp.float32)
+        m_new = (jnp.float32(b1) * mom_refs[0][0].astype(jnp.float32)
                  + jnp.float32(1.0 - b1) * d)
-        v_new = (jnp.float32(b2) * mom_refs[1][...].astype(jnp.float32)
+        v_new = (jnp.float32(b2) * mom_refs[1][0].astype(jnp.float32)
                  + jnp.float32(1.0 - b2) * d * d)
-        m_hat = m_new / (1.0 - jnp.float32(b1) ** t_new)
-        v_hat = v_new / (1.0 - jnp.float32(b2) ** t_new)
+        m_hat = m_new / bias_ref[0]
+        v_hat = v_new / bias_ref[1]
         z_new = zz + jnp.float32(lr) * m_hat / (jnp.sqrt(v_hat)
                                                 + jnp.float32(eps))
         mom_new = (m_new, v_new)
-    z_out_ref[...] = z_new.astype(z_out_ref.dtype)
+    z_out_ref[0] = z_new.astype(z_out_ref.dtype)
     for out_ref, mn in zip(mom_out_refs, mom_new):
-        out_ref[...] = mn.astype(out_ref.dtype)
-    acc_ref[0, 0] = jnp.sum(d * d)
+        out_ref[0] = mn.astype(out_ref.dtype)
+    dsq = d * d
+    if mask:
+        dsq = jnp.where(valid(n, br, pl.program_id(0)), dsq, 0.0)
+    acc_ref[0] = fold(dsq)
 
 
 # ---------------------------------------------------------------------------
@@ -293,56 +290,90 @@ def _outer_kernel(*refs, spec, slots):
 # ---------------------------------------------------------------------------
 
 def _uplink_call(kernel, scalars, vectors, out_vectors, acc, m, n, block,
-                 interpret, dtype):
-    """Shared pallas_call plumbing for the (M, nb)-grid uplink kernels."""
-    nb = (n + (-n) % block) // block
-    in_specs, args = [], []
-    for spec, a in scalars:
-        in_specs.append(spec)
-        args.append(a)
-    for v in vectors:
-        in_specs.append(_row_spec(block))
-        args.append(_tile_rows(v, block))
-    out_specs, out_shape = [], []
-    for _ in range(out_vectors):
-        out_specs.append(_row_spec(block))
-        out_shape.append(jax.ShapeDtypeStruct((m, nb * block), dtype))
+                 interpret, dtype, **static):
+    """Shared pallas_call plumbing for the (M, nb)-grid uplink kernels:
+    ``scalars`` are whole (M,)-vectors in SMEM, ``vectors`` stream as
+    (1, br, 128) blocks, and ``acc`` adds one (8, 128) partial tile per
+    block. Every kernel gets the block geometry ``n``, ``br``, ``mask``."""
+    rows = num_rows(n)
+    br = block_rows(rows, len(vectors) + out_vectors, block)
+    nb = pl.cdiv(rows, br)
+    row = pl.BlockSpec((1, br, LANES), lambda i, j: (i, j, 0))
+    out_specs = [row] * out_vectors
+    out_shape = [jax.ShapeDtypeStruct((m, rows, LANES), dtype)] * out_vectors
     if acc:
-        out_specs.append(_acc_spec())
-        out_shape.append(jax.ShapeDtypeStruct((m, nb), jnp.float32))
+        out_specs.append(pl.BlockSpec((1, SUBLANES, LANES),
+                                      lambda i, j: (i, j, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((m, nb * SUBLANES, LANES),
+                                              jnp.float32))
     outs = pl.pallas_call(
-        kernel,
+        functools.partial(kernel, n=n, br=br, mask=needs_mask(n, rows, br),
+                          **static),
         grid=(m, nb),
-        in_specs=in_specs,
+        in_specs=[smem()] * len(scalars) + [row] * len(vectors),
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
-    )(*args)
+    )(*scalars, *(to_slab(v, rows) for v in vectors))
     outs = list(outs) if isinstance(outs, (list, tuple)) else [outs]
-    return [o[:, :n] for o in outs[:out_vectors]] + outs[out_vectors:]
+    return [from_slab(o, n) for o in outs[:out_vectors]] + outs[out_vectors:]
 
 
-def _w_arg(w):
-    return (_scalar_spec(), jnp.asarray(w, jnp.float32).reshape(-1, 1))
+def _fleet_call(kernel, scalars, vectors, out_dtypes, *, m, n, block,
+                interpret, tiles=(), acc=False, **static):
+    """Shared pallas_call plumbing for the (nb,)-grid server kernels:
+    ``vectors`` stream as full-fleet (M, br, 128) blocks; ``out_dtypes``
+    name the leaf-shaped outputs, and ``acc`` adds one (8, 128) partial
+    tile per block. Every kernel gets ``m`` and the block geometry."""
+    rows = num_rows(n)
+    br = block_rows(rows, m * (len(vectors) + len(out_dtypes)), block)
+    nb = pl.cdiv(rows, br)
+    full = pl.BlockSpec((m, br, LANES), lambda j: (0, j, 0))
+    out_specs = [full] * len(out_dtypes)
+    out_shape = [jax.ShapeDtypeStruct((m, rows, LANES), d)
+                 for d in out_dtypes]
+    if acc:
+        out_specs.append(pl.BlockSpec((1, SUBLANES, LANES),
+                                      lambda j: (0, j, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((1, nb * SUBLANES, LANES),
+                                              jnp.float32))
+    outs = pl.pallas_call(
+        functools.partial(kernel, m=m, n=n, br=br,
+                          mask=needs_mask(n, rows, br), **static),
+        grid=(nb,),
+        in_specs=([smem()] * len(scalars)
+                  + [pl.BlockSpec(t.shape, lambda j: (0,) * t.ndim)
+                     for t in tiles]
+                  + [full] * len(vectors)),
+        out_specs=out_specs,
+        out_shape=out_shape,
+        interpret=interpret,
+    )(*scalars, *tiles, *(to_slab(v, rows) for v in vectors))
+    outs = list(outs) if isinstance(outs, (list, tuple)) else [outs]
+    k = len(out_dtypes)
+    return [from_slab(o, n) for o in outs[:k]] + outs[k:]
 
 
-def uplink_stats(z, w=None, ef=None, *, block: int = 4096,
+def _vec(x, dtype=jnp.float32):
+    return jnp.asarray(x, dtype).reshape(-1)
+
+
+def uplink_stats(z, w=None, ef=None, *, block: int | None = None,
                  interpret: bool = False):
     """Quantize pass 1 on a stacked (M, n) leaf: per-worker ``max|w·z+ef|``
     without materializing the effective message. Returns ``(M,)`` maxima
     (caller applies the 1e-30 clamp)."""
     m, n = z.shape
-    scalars = [] if w is None else [_w_arg(w)]
+    scalars = [] if w is None else [_vec(w)]
     vectors = [z] + ([] if ef is None else [ef])
-    kernel = functools.partial(_stats_kernel, has_w=w is not None,
-                               has_ef=ef is not None)
-    (acc,) = _uplink_call(kernel, scalars, vectors, 0, True, m, n, block,
-                          interpret, z.dtype)
-    return jnp.max(acc, axis=1)
+    (acc,) = _uplink_call(_stats_kernel, scalars, vectors, 0, True, m, n,
+                          block, interpret, z.dtype, has_w=w is not None,
+                          has_ef=ef is not None)
+    return jnp.max(acc, axis=(1, 2))
 
 
 def quantize_uplink(z, seeds, scale, w=None, ef=None, alive=None, *,
-                    levels: float, block: int = 4096,
+                    levels: float, block: int | None = None,
                     interpret: bool = False):
     """Quantize pass 2: one fused sweep doing EF add + w scaling +
     stochastic quantization (threefry bits in-register) + residual
@@ -353,176 +384,124 @@ def quantize_uplink(z, seeds, scale, w=None, ef=None, alive=None, *,
     Returns ``(sent, ef_new)`` (``ef_new`` is None when ``ef`` is None).
     """
     m, n = z.shape
-    scalars = [] if w is None else [_w_arg(w)]
-    scalars.append((_scalar_spec(),
-                    jnp.asarray(scale, jnp.float32).reshape(-1, 1)))
+    scalars = [] if w is None else [_vec(w)]
+    scalars.append(_vec(scale))
     if alive is not None:
-        scalars.append(_w_arg(alive))
-    scalars.append((_seed_spec(),
-                    jnp.asarray(seeds, jnp.uint32).reshape(m, 2)))
+        scalars.append(_vec(alive))
+    scalars.append(_vec(seeds, jnp.uint32))
     vectors = [z] + ([] if ef is None else [ef])
-    kernel = functools.partial(
-        _quantize_kernel, levels=levels, block=block, has_w=w is not None,
-        has_ef=ef is not None, has_alive=alive is not None,
+    outs = _uplink_call(
+        _quantize_kernel, scalars, vectors, 1 + (ef is not None), False, m,
+        n, block, interpret, z.dtype, levels=levels,
+        has_w=w is not None, has_ef=ef is not None,
+        has_alive=alive is not None,
     )
-    outs = _uplink_call(kernel, scalars, vectors, 1 + (ef is not None),
-                        False, m, n, block, interpret, z.dtype)
     return (outs[0], outs[1]) if ef is not None else (outs[0], None)
 
 
-def eff_uplink(z, w=None, ef=None, *, block: int = 4096,
+def eff_uplink(z, w=None, ef=None, *, block: int | None = None,
                interpret: bool = False):
     """Top-k pass 1: materialize ``eff = w·z + ef`` in one fused sweep."""
     m, n = z.shape
-    scalars = [] if w is None else [_w_arg(w)]
+    scalars = [] if w is None else [_vec(w)]
     vectors = [z] + ([] if ef is None else [ef])
-    kernel = functools.partial(_eff_kernel, has_w=w is not None,
-                               has_ef=ef is not None)
-    (out,) = _uplink_call(kernel, scalars, vectors, 1, False, m, n, block,
-                          interpret, z.dtype)
+    (out,) = _uplink_call(_eff_kernel, scalars, vectors, 1, False, m, n,
+                          block, interpret, z.dtype, has_w=w is not None,
+                          has_ef=ef is not None)
     return out
 
 
 def mask_uplink(eff, mask, ef=None, alive=None, *, want_ef: bool = True,
-                block: int = 4096, interpret: bool = False):
+                block: int | None = None, interpret: bool = False):
     """Top-k pass 2: apply the survivor mask and write the complementary
     residual in the same sweep. ``ef`` (the pre-round residual) is only
     read when ``alive`` is given, to freeze dead workers' memory.
     Returns ``(sent, ef_new)`` (``ef_new`` None when ``want_ef`` is False).
     """
     m, n = eff.shape
-    scalars = [] if alive is None else [_w_arg(alive)]
+    scalars = [] if alive is None else [_vec(alive)]
     vectors = [eff, mask]
     if want_ef and alive is not None:
         vectors.append(jnp.zeros_like(eff) if ef is None else ef)
-    kernel = functools.partial(_mask_kernel, has_ef=want_ef,
-                               has_alive=alive is not None)
-    outs = _uplink_call(kernel, scalars, vectors, 1 + want_ef, False, m, n,
-                        block, interpret, eff.dtype)
+    outs = _uplink_call(_mask_kernel, scalars, vectors, 1 + want_ef, False,
+                        m, n, block, interpret, eff.dtype, has_ef=want_ef,
+                        has_alive=alive is not None)
     return (outs[0], outs[1]) if want_ef else (outs[0], None)
 
 
 def merge_stacked(z, w=None, recv=None, old=None, *, normalize: bool = False,
-                  block: int = 4096, interpret: bool = False):
+                  block: int | None = None, interpret: bool = False):
     """Fused server merge on a stacked (M, n) leaf: weighted sum over the
-    worker axis (weights optionally normalized in-register — the Line-7
+    worker axis (weights optionally normalized first — the Line-7
     renormalization over survivors) broadcast back to every worker, with
     non-receiving workers (``recv`` falsy) keeping ``old``.
     """
     m, n = z.shape
-    nb = (n + (-n) % block) // block
-    in_specs, args = [], []
-
-    def vec_smem(v):
-        in_specs.append(pl.BlockSpec((1, m), lambda j: (0, 0),
-                                     memory_space=pltpu.SMEM))
-        args.append(jnp.asarray(v, jnp.float32).reshape(1, m))
-
+    scalars = [] if recv is None else [_vec(recv)]
+    tiles = []
     if w is not None:
-        vec_smem(w)
-    if recv is not None:
-        vec_smem(recv)
-    full_spec = pl.BlockSpec((m, block), lambda j: (0, j))
-    in_specs.append(full_spec)
-    args.append(_tile_rows(z, block))
-    if recv is not None:
-        in_specs.append(full_spec)
-        args.append(_tile_rows(z if old is None else old, block))
-    kernel = functools.partial(
-        _merge_kernel, m=m, normalize=normalize, has_w=w is not None,
+        w = _vec(w)
+        if normalize:
+            w = w / jnp.sum(w)
+        tiles.append(jnp.broadcast_to(w.reshape(m, 1, 1), (m, 1, LANES)))
+    vectors = [z] + ([] if recv is None else [z if old is None else old])
+    (out,) = _fleet_call(
+        _merge_kernel, scalars, vectors, [z.dtype], m=m, n=n, block=block,
+        interpret=interpret, tiles=tiles, has_w=w is not None,
         has_recv=recv is not None,
     )
-    out = pl.pallas_call(
-        kernel,
-        grid=(nb,),
-        in_specs=in_specs,
-        out_specs=full_spec,
-        out_shape=jax.ShapeDtypeStruct((m, nb * block), z.dtype),
-        interpret=interpret,
-    )(*args)
-    return out[:, :n]
+    return out
 
 
 def trimmed_merge_stacked(z, w, incl, recv=None, old=None, *, trim: int,
-                          block: int = 4096, interpret: bool = False):
+                          block: int | None = None, interpret: bool = False):
     """Fused *robust* server merge on a stacked (M, n) leaf: per-coordinate
     ``trim``-per-side trimmed weighted mean over the included rows
     (``incl`` — 0/1, dead/unselected lanes never enter the order
     statistics), renormalized over the survivors' weight mass and broadcast
     back. ``trim = ⌊(M−1)/2⌋`` is the coordinate median. Same (nb,)-grid
-    full-fleet tile layout as :func:`merge_stacked`; ``recv``/``old`` gate
+    full-fleet block layout as :func:`merge_stacked`; ``recv``/``old`` gate
     delivery identically.
     """
     m, n = z.shape
-    nb = (n + (-n) % block) // block
-    in_specs, args = [], []
-
-    def vec_smem(v):
-        in_specs.append(pl.BlockSpec((1, m), lambda j: (0, 0),
-                                     memory_space=pltpu.SMEM))
-        args.append(jnp.asarray(v, jnp.float32).reshape(1, m))
-
-    vec_smem(w)
-    vec_smem(incl)
+    incl = _vec(incl)
+    n_incl = jnp.sum(incl)
+    b = jnp.minimum(jnp.float32(trim), jnp.floor((n_incl - 1.0) * 0.5))
+    scalars = [_vec(w), incl, jnp.stack([b, n_incl - 1.0 - b])]
     if recv is not None:
-        vec_smem(recv)
-    full_spec = pl.BlockSpec((m, block), lambda j: (0, j))
-    in_specs.append(full_spec)
-    args.append(_tile_rows(z, block))
-    if recv is not None:
-        in_specs.append(full_spec)
-        args.append(_tile_rows(z if old is None else old, block))
-    kernel = functools.partial(_trimmed_kernel, m=m, trim=trim,
-                               has_recv=recv is not None)
-    out = pl.pallas_call(
-        kernel,
-        grid=(nb,),
-        in_specs=in_specs,
-        out_specs=full_spec,
-        out_shape=jax.ShapeDtypeStruct((m, nb * block), z.dtype),
-        interpret=interpret,
-    )(*args)
-    return out[:, :n]
+        scalars.append(_vec(recv))
+    vectors = [z] + ([] if recv is None else [z if old is None else old])
+    (out,) = _fleet_call(
+        _trimmed_kernel, scalars, vectors, [z.dtype], m=m, n=n, block=block,
+        interpret=interpret, has_recv=recv is not None,
+    )
+    return out
 
 
-def outer_apply(merged, z, mom, t, *, spec, block: int = 4096,
+def outer_apply(merged, z, mom, t, *, spec, block: int | None = None,
                 interpret: bool = False):
     """Fused server outer-optimizer step on one server leaf ``(1, n)``:
     Δ = merged − z, one moment update + apply of the static ``spec``
     policy (``repro.ps.server_opt`` tuples), all in-register on the same
-    ``(nb,)``-grid full-row tiles as :func:`merge_stacked`.
+    ``(nb,)``-grid blocks as :func:`merge_stacked`.
 
     ``mom`` is the tuple of moment leaves (matched to the policy's slot
-    count), ``t`` the f32 round count before this step (SMEM scalar —
-    only adam's bias correction reads it). Returns
-    ``(z_new, mom_new, delta_sq)`` with ``delta_sq = Σ Δ²`` reduced from
-    the per-tile SMEM accumulator. Padding is zero-filled on every input,
-    so pad lanes contribute exact zeros to moments, step and Δ² alike.
+    count), ``t`` the f32 round count before this step (only adam's bias
+    correction reads it). Returns ``(z_new, mom_new, delta_sq)`` with
+    ``delta_sq = Σ Δ²`` summed from the per-block partial tiles.
     """
-    m, n = z.shape
-    nb = (n + (-n) % block) // block
+    if z.shape[0] != 1:
+        raise ValueError(f"outer_apply takes a (1, n) leaf, got {z.shape}")
+    n = z.shape[1]
+    bias = jnp.ones((2,), jnp.float32)
+    if spec[0] == "adam":
+        t_new = jnp.asarray(t, jnp.float32) + 1.0
+        bias = jnp.stack([1.0 - jnp.float32(spec[2]) ** t_new,
+                          1.0 - jnp.float32(spec[3]) ** t_new])
+    outs = _fleet_call(
+        _outer_kernel, [bias], [merged, z, *mom],
+        [z.dtype] + [mm.dtype for mm in mom], m=1, n=n, block=block,
+        interpret=interpret, spec=spec, slots=len(mom), acc=True,
+    )
     slots = len(mom)
-    full_spec = pl.BlockSpec((m, block), lambda j: (0, j))
-    t_spec = pl.BlockSpec((1, 1), lambda j: (0, 0),
-                          memory_space=pltpu.SMEM)
-    acc_spec = pl.BlockSpec((1, 1), lambda j: (0, j),
-                            memory_space=pltpu.SMEM)
-    args = [jnp.asarray(t, jnp.float32).reshape(1, 1),
-            _tile_rows(merged, block), _tile_rows(z, block)]
-    args += [_tile_rows(mm, block) for mm in mom]
-    out_shape = [jax.ShapeDtypeStruct((m, nb * block), z.dtype)]
-    out_shape += [jax.ShapeDtypeStruct((m, nb * block), mm.dtype)
-                  for mm in mom]
-    out_shape.append(jax.ShapeDtypeStruct((1, nb), jnp.float32))
-    kernel = functools.partial(_outer_kernel, spec=spec, slots=slots)
-    outs = pl.pallas_call(
-        kernel,
-        grid=(nb,),
-        in_specs=[t_spec] + [full_spec] * (2 + slots),
-        out_specs=[full_spec] * (1 + slots) + [acc_spec],
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*args)
-    z_new = outs[0][:, :n]
-    mom_new = tuple(o[:, :n] for o in outs[1:1 + slots])
-    return z_new, mom_new, jnp.sum(outs[-1])
+    return outs[0], tuple(outs[1:1 + slots]), jnp.sum(outs[-1])

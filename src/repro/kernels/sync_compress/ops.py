@@ -6,8 +6,8 @@ serial engines' message-scale-compress-residual tree pipeline (and
 :func:`codec_uplink` the per-shard / single-worker form), while
 :func:`sync_merge_stacked` replaces the weighted-sum-broadcast server side
 (``core.adaseg.sync_weighted_stacked(backend="fused")`` routes here too).
-They fall back to interpret mode automatically off-TPU and to the pure-jnp
-references in :mod:`.ref` with ``use_kernel=False``.
+They run in interpret mode on the CPU (:func:`..tiling.interpret_mode`)
+and fall back to the pure-jnp references in :mod:`.ref` with ``use_kernel=False``.
 
 Codecs are passed as a static *spec* (mirroring the projection specs of
 ``kernels.adaseg_update``) so the kernels can fuse them without a semantics
@@ -54,6 +54,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from ..tiling import interpret_mode
 from . import ref as _ref
 from .kernel import (
     eff_uplink,
@@ -68,16 +69,6 @@ from .kernel import (
 PyTree = Any
 
 _CODECS = ("identity", "quantize", "topk")
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _leaf_block(block, n, interp):
-    """One block per (worker, leaf) row in interpret mode — a single fused
-    jnp sweep off-TPU; the VMEM-sized block stands on hardware."""
-    return max(n, 1) if interp else block
 
 
 def _check_codec(codec):
@@ -111,7 +102,7 @@ _STATIC = ("codec", "use_kernel", "block")
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def codec_uplink_stacked(payload, rngs, w=None, ef=None, alive=None, *,
-                         codec, use_kernel=True, block=4096):
+                         codec, use_kernel=True, block=None):
     """The whole Line-5 uplink for M stacked workers in fused sweeps:
     per-leaf, apply the Line-7 weight ``w``, add the error-feedback
     residual ``ef``, run the codec, and write the new residual back.
@@ -129,7 +120,7 @@ def codec_uplink_stacked(payload, rngs, w=None, ef=None, alive=None, *,
     leaves, treedef = jax.tree.flatten(payload)
     ef_leaves = (treedef.flatten_up_to(ef) if ef is not None
                  else [None] * len(leaves))
-    interp = not _on_tpu()
+    interp = interpret_mode() if use_kernel else False
     w = None if w is None else jnp.asarray(w, jnp.float32)
     alive = None if alive is None else jnp.asarray(alive, jnp.float32)
 
@@ -144,8 +135,7 @@ def codec_uplink_stacked(payload, rngs, w=None, ef=None, alive=None, *,
         shape = z.shape
         z2 = _flat2(z)
         e2 = None if e is None else _flat2(e)
-        n = z2.shape[1]
-        kw = dict(block=_leaf_block(block, n, interp), interpret=interp)
+        kw = dict(block=block, interpret=interp)
 
         if kind == "identity":
             if use_kernel:
@@ -207,7 +197,7 @@ def codec_uplink_stacked(payload, rngs, w=None, ef=None, alive=None, *,
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def codec_uplink(payload, rng, w=None, ef=None, alive=None, *, codec,
-                 use_kernel=True, block=4096):
+                 use_kernel=True, block=None):
     """Single-worker form of :func:`codec_uplink_stacked` (no leading worker
     axis) — the per-shard uplink of the ``shard_map`` engines and the
     stateless ``make_compressed_psum_sync`` hook. ``w``/``alive`` are
@@ -255,7 +245,7 @@ def _krum_select(z2s, w, *, f, m_sel):
                    static_argnames=("normalize", "agg", "use_kernel",
                                     "block"))
 def sync_merge_stacked(z, w=None, recv=None, old=None, *, normalize=False,
-                       agg=None, use_kernel=True, block=4096):
+                       agg=None, use_kernel=True, block=None):
     """The fused Line-7 server side on a worker-stacked pytree: weighted sum
     over the worker axis (``w`` raw weights, normalized in-register when
     ``normalize``) broadcast back to every worker — one read + one write of
@@ -279,7 +269,7 @@ def sync_merge_stacked(z, w=None, recv=None, old=None, *, normalize=False,
     leaves, treedef = jax.tree.flatten(z)
     old_leaves = (treedef.flatten_up_to(old) if old is not None
                   else [None] * len(leaves))
-    interp = not _on_tpu()
+    interp = interpret_mode() if use_kernel else False
     w = None if w is None else jnp.asarray(w, jnp.float32)
     recv = None if recv is None else jnp.asarray(recv, jnp.float32)
     m = leaves[0].shape[0]
@@ -299,11 +289,10 @@ def sync_merge_stacked(z, w=None, recv=None, old=None, *, normalize=False,
             shape = zl.shape
             z2 = _flat2(zl)
             o2 = None if ol is None else _flat2(ol)
-            n = z2.shape[1]
             if use_kernel:
                 out2 = trimmed_merge_stacked(
                     z2, wt, incl, recv, o2, trim=trim,
-                    block=_leaf_block(block, n, interp), interpret=interp,
+                    block=block, interpret=interp,
                 )
             else:
                 out2 = _ref.trimmed_merge_ref(
@@ -318,11 +307,10 @@ def sync_merge_stacked(z, w=None, recv=None, old=None, *, normalize=False,
         shape = zl.shape
         z2 = _flat2(zl)
         o2 = None if ol is None else _flat2(ol)
-        n = z2.shape[1]
         if use_kernel:
             out2 = merge_stacked(
                 z2, w, recv, o2, normalize=normalize,
-                block=_leaf_block(block, n, interp), interpret=interp,
+                block=block, interpret=interp,
             )
         else:
             out2 = _ref.merge_ref(z2, w, normalize=normalize,
@@ -335,7 +323,7 @@ def sync_merge_stacked(z, w=None, recv=None, old=None, *, normalize=False,
 @functools.partial(jax.jit,
                    static_argnames=("spec", "use_kernel", "block"))
 def server_outer_apply(merged, z, mom, t, *, spec, use_kernel=True,
-                       block=4096):
+                       block=None):
     """The server's outer-optimizer step on pytrees: per leaf, form the
     round delta Δ = merged − z and apply one fused moment update + step of
     the static ``spec`` policy (``repro.ps.server_opt`` tuples) — one
@@ -368,7 +356,7 @@ def server_outer_apply(merged, z, mom, t, *, spec, use_kernel=True,
     >>> bool(np.allclose(dn, jnp.sqrt(jnp.sum(merged["p"] ** 2))))
     True
     """
-    interp = not _on_tpu()
+    interp = interpret_mode() if use_kernel else False
     z_leaves, treedef = jax.tree.flatten(z)
     g_leaves = treedef.flatten_up_to(merged)
     mom_leaves = [treedef.flatten_up_to(mm) for mm in mom]
@@ -380,11 +368,10 @@ def server_outer_apply(merged, z, mom, t, *, spec, use_kernel=True,
         shape = zl.shape
         g2, z2 = _flat2(g), _flat2(zl)
         m2 = tuple(_flat2(ml[i]) for ml in mom_leaves)
-        n = z2.shape[1]
         if use_kernel:
             zn2, mn2, ds = outer_apply(
                 g2, z2, m2, t_f, spec=spec,
-                block=_leaf_block(block, n, interp), interpret=interp,
+                block=block, interpret=interp,
             )
         else:
             zn2, mn2, ds = _ref.outer_apply_ref(g2, z2, m2, t_f, spec=spec)

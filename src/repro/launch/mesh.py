@@ -5,14 +5,10 @@ Kept as functions — importing this module never touches jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def _mesh(shape, axes):
-    try:
-        from jax.sharding import AxisType  # JAX >= 0.5
-    except ImportError:
-        return jax.make_mesh(shape, axes)
-
     return jax.make_mesh(
         shape, axes, axis_types=(AxisType.Auto,) * len(axes)
     )

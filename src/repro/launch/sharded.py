@@ -41,7 +41,6 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..core.adaseg import (
@@ -189,14 +188,14 @@ def run_local_adaseg_sharded(
         hist_out = jax.tree.map(lambda v: v[:, :, None], hist)  # (R, K, 1)
         return z_bar, state_out, hist_out
 
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=tuple(in_specs),
         # Prefix specs: z_bar replicated (post-psum), state leaves carry the
         # leading worker axis, history is (R, K, M) with M sharded.
         out_specs=(P(), spec_w, P(None, None, lead)),
-        check_rep=False,
+        check_vma=False,
     )
     z_bar, state, hist = jax.jit(fn)(*operands)
     return z_bar, (state, hist if collect_aux else None)

@@ -311,6 +311,7 @@ def make_ps_engine(
     schedule=None,
     compressor=None,
     faults=None,
+    backend: str = "reference",
     codec_backend: str = "reference",
     latency=None,
     staleness_bound: float | None = None,
@@ -330,6 +331,8 @@ def make_ps_engine(
 
     * ``mesh=None`` — serial vmap engine (``plan.workers_override`` sets M).
     * ``mesh=...``  — ``shard_map`` engine over ``plan.worker_axes(mesh)``.
+    * ``backend``/``codec_backend`` — the AdaSEG step and sync-codec
+      implementations (``"reference"`` or ``"fused"`` Pallas kernels).
     * ``latency``/``staleness_bound`` — :class:`repro.ps.AsyncPSEngine`
       discrete-event simulation instead (serial path; τ=0 is bit-exact with
       the synchronous engine by shared code).
@@ -353,7 +356,7 @@ def make_ps_engine(
         plan.cfg, batch=b, seq=plan.seq,
         hetero_workers=(m if hetero else None),
     )
-    worker = ModelWorker(plan.adaseg, arch=plan.cfg.name)
+    worker = ModelWorker(plan.adaseg, backend=backend, arch=plan.cfg.name)
     if eval_fn == "loss":
         eval_fn = make_eval_loss(plan.cfg, batch=b, seq=plan.seq)
 

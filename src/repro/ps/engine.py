@@ -962,13 +962,26 @@ class PSEngine:
         rng0, worker_rngs = self.worker.derive_rngs(jnp.asarray(rng), m)
         self._rng0 = np.asarray(rng0)
         self._round_rngs = jax.random.split(rng0, r)          # (R, 2)
-        self._state: PyTree = jax.vmap(
-            lambda rr, w: self.worker.init(problem, rr, w)
-        )(worker_rngs, jnp.arange(m, dtype=jnp.int32))
-        self._ef: PyTree = (
-            tree_zeros_like(self.worker.sync_payload(self._state))
-            if self.compressor.error_feedback else ()
-        )
+
+        def init_state(rngs):
+            state = jax.vmap(lambda rr, w: self.worker.init(problem, rr, w))(
+                rngs, jnp.arange(m, dtype=jnp.int32))
+            ef = (tree_zeros_like(self.worker.sync_payload(state))
+                  if self.compressor.error_feedback else ())
+            return state, ef
+
+        if mesh is None:
+            self._state, self._ef = init_state(worker_rngs)
+        else:
+            # Each worker's state is made on its own device: made on one
+            # device first, all M workers' state would have to fit there.
+            from jax.sharding import NamedSharding, PartitionSpec as P
+
+            axes = self._worker_axes
+            on_workers = NamedSharding(
+                mesh, P(axes if len(axes) > 1 else axes[0]))
+            self._state, self._ef = jax.jit(
+                init_state, out_shardings=on_workers)(worker_rngs)
         self.round = 0
 
         # Outer-optimizer state (z_server, moment trees, round count). The
@@ -1069,7 +1082,6 @@ class PSEngine:
         )
 
     def _make_sharded_chunk(self):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         problem, worker = self.problem, self.worker
@@ -1165,13 +1177,13 @@ class PSEngine:
             return state_out, ef_out, etas[:, None]           # (C, 1)
 
         spec_w = P(lead)
-        fn = shard_map(
+        fn = jax.shard_map(
             shard_fn,
             mesh=self._mesh,
             in_specs=(spec_w, spec_w, P(lead, None, None, None),
                       P(lead, None, None), P(lead, None), P(lead, None)),
             out_specs=(spec_w, spec_w, P(None, lead)),
-            check_rep=False,
+            check_vma=False,
         )
 
         jfn = jax.jit(fn)
@@ -1207,32 +1219,48 @@ class PSEngine:
     # Driving, output, telemetry
     # ------------------------------------------------------------------
 
-    def _run_chunk(self, r0: int, r1: int) -> None:
+    def _chunk_args(self, r0: int, r1: int) -> list:
         sl = slice(r0, r1)
+        if self._draws is not None:
+            args = [
+                self._state, self._ef,
+                jnp.asarray(self._draws[sl]),
+                self._round_rngs[sl],
+                jnp.asarray(self._ks_lane[sl]),
+                jnp.asarray(self._alive_lane[sl]),
+            ]
+            if self._robust is not None:
+                args.append(jnp.asarray(self._byz_lane[sl]))
+        else:
+            args = [
+                self._state, self._ef,
+                self._round_rngs[sl],
+                jnp.asarray(self._ks[sl]),
+                jnp.asarray(self._alive[sl]),
+            ]
+            if self._robust is not None:
+                args.append(jnp.asarray(self._byz[sl]))
+        args.append(jnp.asarray(self._counts_cum[sl]))
+        if self._server is not None:
+            args.append(self._srv)
+        return args
+
+    def lower_rounds(self, rounds: int = 1):
+        """The serial round program for the next ``rounds`` rounds, lowered
+        and not run (``jax.stages.Lowered``): ``.compile()`` gives its
+        ``memory_analysis()`` and HLO text before any device memory is
+        spent on it. The sharded path jits only its ``shard_map`` body and
+        has no such single program."""
+        if self._mesh is not None:
+            raise NotImplementedError("lower_rounds covers the serial path")
+        r1 = min(self.round + rounds, self.config.rounds)
+        return self._chunk_fn.lower(*self._chunk_args(self.round, r1))
+
+    def _run_chunk(self, r0: int, r1: int) -> None:
         with self.tracer.span(f"chunk [{r0},{r1})", cat="chunk",
                               rounds=r1 - r0) as chunk_sp:
-            if self._draws is not None:
-                args = [
-                    self._state, self._ef,
-                    jnp.asarray(self._draws[sl]),
-                    self._round_rngs[sl],
-                    jnp.asarray(self._ks_lane[sl]),
-                    jnp.asarray(self._alive_lane[sl]),
-                ]
-                if self._robust is not None:
-                    args.append(jnp.asarray(self._byz_lane[sl]))
-            else:
-                args = [
-                    self._state, self._ef,
-                    self._round_rngs[sl],
-                    jnp.asarray(self._ks[sl]),
-                    jnp.asarray(self._alive[sl]),
-                ]
-                if self._robust is not None:
-                    args.append(jnp.asarray(self._byz[sl]))
-            args.append(jnp.asarray(self._counts_cum[sl]))
+            args = self._chunk_args(r0, r1)
             if self._server is not None:
-                args.append(self._srv)
                 (state, ef, etas, ress,
                  self._srv, outer) = self._chunk_fn(*args)
             else:
@@ -1379,9 +1407,14 @@ class PSEngine:
         counts = counts.astype(np.float32)
         if counts.sum() == 0.0:
             counts = np.ones_like(counts)
-        return weighted_worker_average(
+        z = weighted_worker_average(
             self.worker.output(self._state), jnp.asarray(counts)
         )
+        if self._mesh is not None:
+            # On one device: code that evaluates z̄ is jitted outside any
+            # shard_map, where a Pallas kernel cannot span the mesh.
+            z = jax.device_put(z, self._mesh.devices.flat[0])
+        return z
 
     # ------------------------------------------------------------------
     # Checkpointing

@@ -15,23 +15,14 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import AbstractMesh, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 
 def abstract_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Device-free ``AbstractMesh`` across the JAX constructor API change.
-
-    JAX ≤ 0.4.x takes a single ``shape_tuple`` of ``(name, size)`` pairs;
-    0.5+ takes ``(axis_sizes, axis_names)``. Spec logic only ever reads
-    ``mesh.shape`` / ``mesh.axis_names``, which both forms provide.
-    """
-    from jax.sharding import AbstractMesh
-
-    try:
-        return AbstractMesh(shape, axes)
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))
+    """Device-free ``AbstractMesh`` for spec logic, which only reads
+    ``mesh.shape`` and ``mesh.axis_names``."""
+    return AbstractMesh(shape, axes)
 
 
 def _axis_size(mesh: Mesh, name) -> int:

@@ -30,7 +30,6 @@ def test_psum_sync_equals_stacked_sync():
     out = run_in_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.core import make_psum_sync, sync_weighted_stacked
         from repro.launch.mesh import make_test_mesh
 
@@ -46,7 +45,7 @@ def test_psum_sync_equals_stacked_sync():
             # per-shard: z {"w": (1, 6)}, ie (1,)
             out = sync({"w": z["w"][0]}, ie[0])
             return {"w": out["w"][None]}, None
-        got, _ = shard_map(
+        got, _ = jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(P("data", None), P("data")),
             out_specs=(P("data", None), None),

@@ -334,7 +334,10 @@ def test_adam_moments_are_convex_averages(b1, b2, deltas):
     v ≤ max(Δ²) componentwise — with no 1/(1−β) inflation."""
     deltas = [d.reshape(1, -1) for d in deltas]
     dmax = max(float(np.abs(d).max()) for d in deltas)
-    _, moms = _ref_chain(("adam", 0.5, b1, b2, 1e-8), deltas,
+    # lr=0 keeps z at 0, so each merged − z is exactly its delta. With a
+    # moving z, a zero delta at β₂=0 gives v̂=0 and a step of lr/ε ≈ 1e7,
+    # after which z + Δ rounds in f32 and the chain applies another Δ.
+    _, moms = _ref_chain(("adam", 0.0, b1, b2, 1e-8), deltas,
                          np.zeros((1, 4), np.float32))
     for m, v in moms:
         assert float(jnp.abs(m).max()) <= dmax + 1e-4
@@ -427,7 +430,8 @@ _V_FIELDS = {
 }
 
 
-@given(st.sampled_from([5, 6, 7, 8]), st.randoms(use_true_random=False))
+@given(version=st.sampled_from([5, 6, 7, 8]),
+       rnd=st.randoms(use_true_random=False))
 @settings(max_examples=40, deadline=None)
 def test_any_trace_vintage_loads_with_defaults(version, rnd, tmp_path_factory):
     import json
