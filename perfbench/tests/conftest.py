@@ -1,0 +1,10 @@
+"""The benchmark's tests import ``perfbench`` and the program from the
+checkout root, wherever pytest is started."""
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+for p in (os.path.join(ROOT, "src"), ROOT):
+    if p not in sys.path:
+        sys.path.insert(0, p)
