@@ -64,6 +64,10 @@ from .types import MinimaxProblem, draw
 PyTree = Any
 SyncFn = Callable[[PyTree, jax.Array], PyTree]
 
+# ``jax.named_scope`` of the update around the oracle calls (both halves of
+# the extragradient step, its η/Z² bookkeeping and the ``enabled`` masks).
+UPDATE_SCOPE = "adaseg-update"
+
 
 @dataclasses.dataclass(frozen=True)
 class AdaSEGConfig:
@@ -136,7 +140,6 @@ def local_step(
     spec = projections.spec_of(problem.project) if backend == "fused" else None
 
     r1, r2 = jax.random.split(rng)
-    eta = eta_of(cfg, state.sum_sq)
     z_star = state.z_tilde
     m_t = problem.oracle(z_star, draw(problem, r1, state.worker_id))  # M_t
 
@@ -149,56 +152,64 @@ def local_step(
         )
 
         d_alpha = cfg.diameter * cfg.alpha
-        z_t, m_sq = adaseg_tree_explore(
-            z_star, m_t, sum_sq=state.sum_sq, g0=cfg.g0, d_alpha=d_alpha,
-            proj=spec,
-        )
-        g_t = problem.oracle(z_t, draw(problem, r2, state.worker_id))  # g_t
-        z_tilde_new, stat, g_sq = adaseg_tree_anchor(
-            z_star, z_t, g_t, sum_sq=state.sum_sq, g0=cfg.g0,
-            d_alpha=d_alpha, proj=spec,
-        )
-        z_sq = stat / (5.0 * eta ** 2)
-        grad_norm_sq = g_sq + m_sq
-    else:
-        z_t = problem.project(tree_axpy(-eta, m_t, z_star))
-        g_t = problem.oracle(z_t, draw(problem, r2, state.worker_id))  # g_t
-        z_tilde_new = problem.project(tree_axpy(-eta, g_t, z_star))
 
-        z_sq = (
-            tree_norm_sq(tree_sub(z_t, z_star))
-            + tree_norm_sq(tree_sub(z_t, z_tilde_new))
-        ) / (5.0 * eta ** 2)
-        grad_norm_sq = tree_norm_sq(g_t) + tree_norm_sq(m_t)
+    with jax.named_scope(UPDATE_SCOPE):
+        eta = eta_of(cfg, state.sum_sq)
+        if spec is not None:
+            z_t, m_sq = adaseg_tree_explore(
+                z_star, m_t, sum_sq=state.sum_sq, g0=cfg.g0,
+                d_alpha=d_alpha, proj=spec,
+            )
+        else:
+            z_t = problem.project(tree_axpy(-eta, m_t, z_star))
 
-    t_new = state.t + 1
-    # Incremental uniform mean of the exploration iterates z_t (Line 14).
-    if cfg.average_output:
-        z_bar_new = jax.tree.map(
-            lambda zb, zt: zb + (zt - zb) / t_new.astype(zt.dtype),
-            state.z_bar,
-            z_t,
-        )
-    else:
-        z_bar_new = z_t
+    g_t = problem.oracle(z_t, draw(problem, r2, state.worker_id))  # g_t
 
-    new = AdaSEGState(
-        z_tilde=z_tilde_new,
-        sum_sq=state.sum_sq + z_sq,
-        t=t_new,
-        z_bar=z_bar_new,
-        grad_sq_sum=state.grad_sq_sum + grad_norm_sq,
-        worker_id=state.worker_id,
-    )
-    if enabled is not None:
+    with jax.named_scope(UPDATE_SCOPE):
+        if spec is not None:
+            z_tilde_new, stat, g_sq = adaseg_tree_anchor(
+                z_star, z_t, g_t, sum_sq=state.sum_sq, g0=cfg.g0,
+                d_alpha=d_alpha, proj=spec,
+            )
+            z_sq = stat / (5.0 * eta ** 2)
+            grad_norm_sq = g_sq + m_sq
+        else:
+            z_tilde_new = problem.project(tree_axpy(-eta, g_t, z_star))
+            z_sq = (
+                tree_norm_sq(tree_sub(z_t, z_star))
+                + tree_norm_sq(tree_sub(z_t, z_tilde_new))
+            ) / (5.0 * eta ** 2)
+            grad_norm_sq = tree_norm_sq(g_t) + tree_norm_sq(m_t)
+
+        t_new = state.t + 1
+        # Incremental uniform mean of the exploration iterates z_t (Line 14).
+        if cfg.average_output:
+            z_bar_new = jax.tree.map(
+                lambda zb, zt: zb + (zt - zb) / t_new.astype(zt.dtype),
+                state.z_bar,
+                z_t,
+            )
+        else:
+            z_bar_new = z_t
+
         new = AdaSEGState(
-            z_tilde=tree_where(enabled, new.z_tilde, state.z_tilde),
-            sum_sq=jnp.where(enabled, new.sum_sq, state.sum_sq),
-            t=jnp.where(enabled, new.t, state.t),
-            z_bar=tree_where(enabled, new.z_bar, state.z_bar),
-            grad_sq_sum=jnp.where(enabled, new.grad_sq_sum, state.grad_sq_sum),
+            z_tilde=z_tilde_new,
+            sum_sq=state.sum_sq + z_sq,
+            t=t_new,
+            z_bar=z_bar_new,
+            grad_sq_sum=state.grad_sq_sum + grad_norm_sq,
             worker_id=state.worker_id,
         )
+        if enabled is not None:
+            new = AdaSEGState(
+                z_tilde=tree_where(enabled, new.z_tilde, state.z_tilde),
+                sum_sq=jnp.where(enabled, new.sum_sq, state.sum_sq),
+                t=jnp.where(enabled, new.t, state.t),
+                z_bar=tree_where(enabled, new.z_bar, state.z_bar),
+                grad_sq_sum=jnp.where(enabled, new.grad_sq_sum,
+                                      state.grad_sq_sum),
+                worker_id=state.worker_id,
+            )
     aux = StepAux(eta=eta, z_sq=z_sq, grad_norm_sq=grad_norm_sq)
     return new, aux
 

@@ -23,6 +23,7 @@ from .layers import (
 )
 
 NEG_INF = -1e30
+ATTENTION_SCOPE = "attention"   # ``jax.named_scope`` of the attention core
 
 
 def init_attention(key, cfg: ArchConfig, *, cross: bool = False):
@@ -189,21 +190,25 @@ def apply_attention(
     s_len, t_len = q.shape[1], k.shape[1]
     scale = cfg.attn_scale or cfg.head_dim_ ** -0.5
     is_self_causal = causal and cross_states is None
-    if is_self_causal and cfg.attn_backend == "pallas":
-        out = _flash_self_attention(
-            q, k, v, scale=scale, cap=cfg.attn_softcap, window=window,
-        )
-    elif is_self_causal and s_len >= CHUNKED_ATTN_THRESHOLD:
-        out = _chunked_attention(
-            q, k, v, scale=scale, cap=cfg.attn_softcap,
-            causal=True, window=window,
-        )
-    else:
-        if is_self_causal:
-            mask = causal_mask(s_len, t_len, window=window)
+    # Scores, softmax and values (with their backward and recompute) carry
+    # the scope ATTENTION_SCOPE on the device profile; the projections not.
+    with jax.named_scope(ATTENTION_SCOPE):
+        if is_self_causal and cfg.attn_backend == "pallas":
+            out = _flash_self_attention(
+                q, k, v, scale=scale, cap=cfg.attn_softcap, window=window,
+            )
+        elif is_self_causal and s_len >= CHUNKED_ATTN_THRESHOLD:
+            out = _chunked_attention(
+                q, k, v, scale=scale, cap=cfg.attn_softcap,
+                causal=True, window=window,
+            )
         else:
-            mask = jnp.ones((s_len, t_len), dtype=bool)
-        out = gqa_scores(q, k, v, mask, scale=scale, cap=cfg.attn_softcap)
+            if is_self_causal:
+                mask = causal_mask(s_len, t_len, window=window)
+            else:
+                mask = jnp.ones((s_len, t_len), dtype=bool)
+            out = gqa_scores(q, k, v, mask, scale=scale,
+                             cap=cfg.attn_softcap)
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"])
 
 

@@ -53,6 +53,10 @@ from .ssm import apply_ssm, decode_ssm, init_ssm, init_ssm_cache, ssm_cache_spec
 
 PyTree = Any
 
+# ``jax.named_scope`` of the vocabulary-wide work on the device profile: the
+# embedding lookup, the head, its log-softmax and the NLL gather.
+VOCAB_SCOPE = "vocab"
+
 
 def init_norm(cfg: ArchConfig):
     dt = jnp.dtype(cfg.param_dtype)
@@ -263,7 +267,8 @@ def forward(params, cfg: ArchConfig, tokens, *, enc_states=None,
     full-vocab logits tensor is O(S·V) and dominates prefill HBM otherwise.
     """
     cdt = jnp.dtype(cfg.compute_dtype)
-    x = apply_embedding(params["embed"], tokens).astype(cdt)
+    with jax.named_scope(VOCAB_SCOPE):
+        x = apply_embedding(params["embed"], tokens).astype(cdt)
     if cfg.scale_embed:
         x = x * jnp.asarray(cfg.d_model**0.5, cdt)
     positions = jnp.broadcast_to(jnp.arange(tokens.shape[1]), tokens.shape)
@@ -302,10 +307,12 @@ def forward(params, cfg: ArchConfig, tokens, *, enc_states=None,
     x = apply_norm(cfg, params["final_norm"], x)
     if head_last_only:
         x = x[:, -1:, :]
-    head = params["embed"]["table"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = (x @ head.astype(cdt)).astype(jnp.float32)
-    if cfg.final_softcap:
-        logits = softcap(logits, cfg.final_softcap)
+    with jax.named_scope(VOCAB_SCOPE):
+        head = (params["embed"]["table"].T if cfg.tie_embeddings
+                else params["lm_head"])
+        logits = (x @ head.astype(cdt)).astype(jnp.float32)
+        if cfg.final_softcap:
+            logits = softcap(logits, cfg.final_softcap)
     return logits, aux_total
 
 
@@ -319,11 +326,12 @@ def loss_fn(params, cfg: ArchConfig, batch):
         enc_states = batch["frontend"].astype(jnp.dtype(cfg.compute_dtype))
     logits, aux = forward(params, cfg, batch["tokens"], enc_states=enc_states)
     labels = batch["labels"]
-    mask = labels >= 0
-    safe = jnp.maximum(labels, 0)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, safe[..., None], axis=-1)[..., 0]
-    loss = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1)
+    with jax.named_scope(VOCAB_SCOPE):
+        mask = labels >= 0
+        safe = jnp.maximum(labels, 0)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(logp, safe[..., None], axis=-1)[..., 0]
+        loss = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1)
     return loss + cfg.router_aux_weight * aux
 
 

@@ -9,9 +9,7 @@ visible without perturbing a single bit of the numerics:
   host wall-clock **and** (in the async engine) simulated-clock intervals,
   on per-worker tracks;
 * :mod:`~repro.obs.metrics` — :class:`MetricsRegistry` counters/gauges/
-  histograms with JSONL sinks, plus :func:`modeled_sync_cost` putting the
-  ``kernels.sync_compress`` HBM-traffic model and the roofline bandwidth
-  constant next to every measured wall time;
+  histograms with JSONL sinks;
 * :mod:`~repro.obs.export` — Chrome/Perfetto trace-event JSON of either
   clock (:func:`save_trace_events`), schema-checked by
   :func:`validate_trace_events`.
@@ -30,7 +28,7 @@ Examples
 >>> validate_trace_events(to_trace_events(tr.spans, clock="sim"))
 """
 from .export import save_trace_events, to_trace_events, validate_trace_events
-from .metrics import MetricsRegistry, modeled_sync_cost
+from .metrics import MetricsRegistry
 from .spans import CATEGORIES, Span, SpanTracer
 
 __all__ = [
@@ -38,7 +36,6 @@ __all__ = [
     "MetricsRegistry",
     "Span",
     "SpanTracer",
-    "modeled_sync_cost",
     "save_trace_events",
     "to_trace_events",
     "validate_trace_events",

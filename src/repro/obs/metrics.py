@@ -1,13 +1,8 @@
 """Counter/gauge/histogram registry with JSONL + in-memory sinks.
 
 The engines emit their quantitative telemetry here — bytes up/down,
-effective local steps, η spread, admitted staleness — and, for the sync hot
-path, a *modeled* cost next to every measured one: HBM passes per uplink
-from the ``kernels.sync_compress`` traffic model
-(:func:`repro.kernels.sync_compress.ops.codec_passes`) converted to seconds
-with the roofline constants of :mod:`repro.roofline.analysis`, so a single
-record answers "how long did the round take, and how long does the traffic
-model say it should take on real HBM".
+effective local steps, η spread, admitted staleness, round wall times and
+the chunk tracings each call set off (``chunk_traces``).
 
 Records are plain dicts (``kind``/``name``/``value``/``labels`` + optional
 ``t_wall``/``t_sim``) accumulated in memory; :meth:`MetricsRegistry.save_jsonl`
@@ -31,7 +26,6 @@ Examples
 from __future__ import annotations
 
 import json
-import math
 from typing import Any
 
 
@@ -126,36 +120,3 @@ class MetricsRegistry:
                     reg.records.append(json.loads(line))
         return reg
 
-
-def modeled_sync_cost(codec_spec: tuple | None, param_bytes: float, *,
-                      workers: int, backend: str = "reference") -> dict:
-    """Roofline-modeled cost of one sync round's uplink hot path.
-
-    Reuses the ``kernels.sync_compress`` HBM traffic model (passes per
-    uplink for the given codec and backend) and the roofline HBM bandwidth
-    constant, so engines can put the *predicted* time next to the measured
-    wall time in one metric record. ``codec_spec=None`` (an opaque
-    compressor without a spec) returns NaNs rather than guessing.
-
-    Examples
-    --------
-    >>> c = modeled_sync_cost(("quantize", 8), 4096.0, workers=4)
-    >>> c["hbm_passes"], c["hbm_bytes"] == 11 * 4096.0 * 4
-    (11, True)
-    >>> f = modeled_sync_cost(("quantize", 8), 4096.0, workers=4,
-    ...                       backend="fused")
-    >>> f["hbm_passes"]
-    6
-    """
-    from ..roofline.analysis import HBM_BW
-
-    if codec_spec is None:
-        return {"hbm_passes": math.nan, "hbm_bytes": math.nan,
-                "hbm_s": math.nan}
-    from ..kernels.sync_compress.ops import codec_passes
-
-    ref_p, fused_p = codec_passes(codec_spec)
-    passes = ref_p if backend == "reference" else fused_p
-    hbm_bytes = float(passes) * float(param_bytes) * int(workers)
-    return {"hbm_passes": passes, "hbm_bytes": hbm_bytes,
-            "hbm_s": hbm_bytes / HBM_BW}

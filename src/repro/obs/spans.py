@@ -47,7 +47,7 @@ from typing import Any, Iterator
 # Canonical phase categories (``Span.cat``). Free-form strings are allowed,
 # but the engines and the Perfetto export color-key on these.
 CATEGORIES = (
-    "run", "chunk", "round", "admission",
+    "run", "args", "chunk", "telemetry", "output", "round", "admission",
     "local-compute", "uplink", "held", "reboot",
     "uplink-encode", "server-merge", "broadcast",
     "eval", "checkpoint",

@@ -89,7 +89,7 @@ from .engine import (
     make_serial_chunk,
     resolve_robust,
 )
-from ..obs import MetricsRegistry, SpanTracer, modeled_sync_cost
+from ..obs import MetricsRegistry, SpanTracer
 from .faults import NoFaults
 from .latency import ConstantLatency, LatencyModel
 from .robust import WeightedMean
@@ -931,16 +931,9 @@ class AsyncPSEngine:
         for m in adm:
             self.metrics.observe("staleness", float(stale[m]),
                                  engine="async", t_sim=t)
-        cost = modeled_sync_cost(
-            getattr(self.compressor, "codec_spec", None),
-            self._dense_bytes, workers=len(adm),
-            backend=self.codec_backend,
-        )
         self.metrics.observe(
             "admission_wall_s", adm_sp.wall_dur, engine="async",
-            codec=self.compressor.name, backend=self.codec_backend,
-            modeled_hbm_passes=cost["hbm_passes"],
-            modeled_hbm_s=cost["hbm_s"], t_sim=t,
+            codec=self.compressor.name, backend=self.codec_backend, t_sim=t,
         )
 
     def _idle_frac(self, t: float) -> float | None:

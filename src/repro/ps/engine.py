@@ -54,7 +54,7 @@ from ..core.adaseg import AdaSEGConfig, weighted_worker_average
 from ..core.tree import tree_add, tree_sub, tree_where, tree_zeros_like
 from ..core.types import MinimaxProblem
 from ..core.worker import AdaSEGWorker, LocalWorker
-from ..obs import MetricsRegistry, SpanTracer, modeled_sync_cost
+from ..obs import MetricsRegistry, SpanTracer
 from .compress import (
     IdentityCompressor,
     SyncCompressor,
@@ -211,8 +211,9 @@ def _count_trace() -> None:
 
 
 def serial_chunk_traces() -> int:
-    """Process-wide count of serial round-chunk tracings (≈ compilations).
-    Regression tests read deltas of this to pin that remainder chunks and
+    """Process-wide count of round-chunk tracings (≈ compilations), serial
+    and sharded. Each chunk span records the delta of its call (``traces``);
+    regression tests read deltas of this to pin that remainder chunks and
     same-config engines do not retrigger compilation."""
     return _TRACE_COUNT
 
@@ -1053,8 +1054,9 @@ class PSEngine:
                     key, self._make_serial_chunk
                 )
         else:
-            # NOT jit-wrapped here: the sharded chunk derives its rng tables
-            # eagerly and jits only the shard_map body — with the default
+            # NOT jit-wrapped here: the sharded chunk's rng tables are
+            # derived eagerly (``_sharded_args``) and only the shard_map
+            # body is jitted — with the default
             # non-partitionable threefry, deriving keys inside the jit that
             # feeds a shard_map re-shards the key computation itself and
             # silently changes the stream (same reason the one-shot sharded
@@ -1094,6 +1096,7 @@ class PSEngine:
         def shard_fn(state_s, ef_s, s_rngs, c_rngs, ks_m, alive_m):
             # Per-shard shapes: state leaves (1, ...), s_rngs (1, C, K, 2),
             # c_rngs (1, C, 2), ks_m/alive_m (1, C).
+            _count_trace()
             st0 = jax.tree.map(lambda v: v[0], state_s)
             ef0 = jax.tree.map(lambda v: v[0], ef_s)
 
@@ -1103,57 +1106,59 @@ class PSEngine:
                 st, ef = carry
                 rngs_round, c_rng, k_m, al = inputs
 
-                # Line 5–8 as one all-reduce of the compressed message.
-                sw = worker.sync_weight(st)
-                if no_faults:
-                    # same expressions as core.adaseg.make_psum_sync
-                    any_alive = None
-                    w = sw / lax.psum(sw, axes)
-                else:
-                    w_raw = jnp.where(al, sw, 0.0)
-                    denom = lax.psum(w_raw, axes)
-                    any_alive = denom > 0.0
-                    w = w_raw / jnp.where(any_alive, denom, 1.0)
-                payload = worker.sync_payload(st)
-                if comp.is_identity:
-                    msg = jax.tree.map(
-                        lambda v: w.astype(v.dtype) * v, payload
-                    )
-                    sent, ef_new = msg, ef
-                elif codec_backend == "fused":
-                    # fused uplink sweep: w scaling + EF add + codec +
-                    # residual write-back, aliveness handled in-kernel
-                    from ..kernels.sync_compress.ops import codec_uplink
+                # Line 5–8 as one all-reduce of the compressed message,
+                # scoped as the serial round's sync.
+                with jax.named_scope("sync"):
+                    sw = worker.sync_weight(st)
+                    if no_faults:
+                        # same expressions as core.adaseg.make_psum_sync
+                        any_alive = None
+                        w = sw / lax.psum(sw, axes)
+                    else:
+                        w_raw = jnp.where(al, sw, 0.0)
+                        denom = lax.psum(w_raw, axes)
+                        any_alive = denom > 0.0
+                        w = w_raw / jnp.where(any_alive, denom, 1.0)
+                    payload = worker.sync_payload(st)
+                    if comp.is_identity:
+                        msg = jax.tree.map(
+                            lambda v: w.astype(v.dtype) * v, payload
+                        )
+                        sent, ef_new = msg, ef
+                    elif codec_backend == "fused":
+                        # fused uplink sweep: w scaling + EF add + codec +
+                        # residual write-back, aliveness handled in-kernel
+                        from ..kernels.sync_compress.ops import codec_uplink
 
-                    sent, ef_new = codec_uplink(
-                        payload, c_rng, w=w,
-                        ef=ef if comp.error_feedback else None,
-                        alive=None if no_faults else al,
-                        codec=comp.codec_spec,
-                    )
-                    if not comp.error_feedback:
-                        ef_new = ef
-                else:
-                    msg = jax.tree.map(
-                        lambda v: w.astype(v.dtype) * v, payload
-                    )
-                    eff = tree_add(msg, ef) if comp.error_feedback else msg
-                    sent = comp.compress(eff, c_rng)
-                    if not no_faults:
-                        sent = tree_where(al, sent, tree_zeros_like(sent))
-                    ef_new = ef
-                    if comp.error_feedback:
-                        ef_new = tree_sub(eff, sent)
+                        sent, ef_new = codec_uplink(
+                            payload, c_rng, w=w,
+                            ef=ef if comp.error_feedback else None,
+                            alive=None if no_faults else al,
+                            codec=comp.codec_spec,
+                        )
+                        if not comp.error_feedback:
+                            ef_new = ef
+                    else:
+                        msg = jax.tree.map(
+                            lambda v: w.astype(v.dtype) * v, payload
+                        )
+                        eff = tree_add(msg, ef) if comp.error_feedback else msg
+                        sent = comp.compress(eff, c_rng)
                         if not no_faults:
-                            ef_new = tree_where(al, ef_new, ef)
-                z_sum = jax.tree.map(lambda v: lax.psum(v, axes), sent)
-                if no_faults:
-                    st = worker.merge_synced(st, z_sum)
-                else:
-                    recv = jnp.logical_and(al, any_alive)
-                    st = worker.merge_synced(
-                        st, tree_where(recv, z_sum, payload)
-                    )
+                            sent = tree_where(al, sent, tree_zeros_like(sent))
+                        ef_new = ef
+                        if comp.error_feedback:
+                            ef_new = tree_sub(eff, sent)
+                            if not no_faults:
+                                ef_new = tree_where(al, ef_new, ef)
+                    z_sum = jax.tree.map(lambda v: lax.psum(v, axes), sent)
+                    if no_faults:
+                        st = worker.merge_synced(st, z_sum)
+                    else:
+                        recv = jnp.logical_and(al, any_alive)
+                        st = worker.merge_synced(
+                            st, tree_where(recv, z_sum, payload)
+                        )
 
                 def body(s, inp):
                     rngs, i = inp
@@ -1163,9 +1168,10 @@ class PSEngine:
                     s = worker.step(problem, s, rngs, enabled=enabled)
                     return s, None
 
-                st, _ = lax.scan(
-                    body, st, (rngs_round, jnp.arange(k_pad))
-                )
+                with jax.named_scope("local-compute"):
+                    st, _ = lax.scan(
+                        body, st, (rngs_round, jnp.arange(k_pad))
+                    )
                 return (st, ef_new), worker.eta(st)
 
             (st, ef), etas = lax.scan(
@@ -1188,38 +1194,45 @@ class PSEngine:
 
         jfn = jax.jit(fn)
 
-        def chunk(state, ef, round_rngs, ks, alive, counts_cum):
-            del counts_cum  # sharded residuals are chunk-boundary only
-            # Eager rng derivation (see __init__): keys must be materialized
-            # before they cross the shard_map boundary.
-            step_rngs = jax.vmap(
-                lambda rr: jax.random.split(rr, k_pad * m).reshape(
-                    k_pad, m, 2
-                )
-            )(round_rngs)                                     # (C, K, M, 2)
-            step_rngs = jnp.transpose(step_rngs, (2, 0, 1, 3))  # (M, C, K, 2)
-            c_rngs = jax.vmap(
-                lambda rr: jax.random.split(jax.random.fold_in(rr, 7), m)
-            )(round_rngs)                                     # (C, M, 2)
-            c_rngs = jnp.transpose(c_rngs, (1, 0, 2))         # (M, C, 2)
-            state, ef, etas = jfn(
-                state, ef, step_rngs, c_rngs,
-                jnp.asarray(ks).T, jnp.asarray(alive).T,
-            )
+        def chunk(state, ef, step_rngs, c_rngs, ks_t, alive_t):
+            # The keys come derived from ``_sharded_args``; the residual of
+            # a sharded chunk is taken at its boundary, on the host.
+            state, ef, etas = jfn(state, ef, step_rngs, c_rngs, ks_t,
+                                  alive_t)
             eta_stats = jnp.stack(
                 [etas.min(axis=1), etas.max(axis=1), etas.mean(axis=1)],
                 axis=1,
             )                                                 # (C, 3)
-            ress = jnp.full((round_rngs.shape[0],), jnp.nan, jnp.float32)
+            ress = jnp.full((etas.shape[0],), jnp.nan, jnp.float32)
             return state, ef, eta_stats, ress
 
         return chunk
+
+    def _sharded_args(self, r0: int, r1: int) -> list:
+        """The sharded chunk's arguments for rounds [r0, r1). Eager rng
+        derivation (see __init__): keys must be materialized before they
+        cross the shard_map boundary."""
+        m, k_pad = self.config.num_workers, self._k_pad
+        round_rngs = self._round_rngs[r0:r1]
+        step_rngs = jax.vmap(
+            lambda rr: jax.random.split(rr, k_pad * m).reshape(k_pad, m, 2)
+        )(round_rngs)                                         # (C, K, M, 2)
+        step_rngs = jnp.transpose(step_rngs, (2, 0, 1, 3))    # (M, C, K, 2)
+        c_rngs = jax.vmap(
+            lambda rr: jax.random.split(jax.random.fold_in(rr, 7), m)
+        )(round_rngs)                                         # (C, M, 2)
+        c_rngs = jnp.transpose(c_rngs, (1, 0, 2))             # (M, C, 2)
+        return [self._state, self._ef, step_rngs, c_rngs,
+                jnp.asarray(self._ks[r0:r1]).T,
+                jnp.asarray(self._alive[r0:r1]).T]
 
     # ------------------------------------------------------------------
     # Driving, output, telemetry
     # ------------------------------------------------------------------
 
     def _chunk_args(self, r0: int, r1: int) -> list:
+        if self._mesh is not None:
+            return self._sharded_args(r0, r1)
         sl = slice(r0, r1)
         if self._draws is not None:
             args = [
@@ -1256,10 +1269,16 @@ class PSEngine:
         r1 = min(self.round + rounds, self.config.rounds)
         return self._chunk_fn.lower(*self._chunk_args(self.round, r1))
 
-    def _run_chunk(self, r0: int, r1: int) -> None:
+    def _run_chunk(self, r0: int, r1: int, run: str) -> None:
+        """Rounds [r0, r1) as one chunk call, inside the span named ``run``.
+        The host's work around the call is spanned as ``<run> args`` and
+        ``<run> telemetry``; the chunk span records ``traces``, the chunk
+        tracings (≈ compilations) its call set off."""
+        with self.tracer.span(f"{run} args", cat="args"):
+            args = self._chunk_args(r0, r1)
+        traces0 = serial_chunk_traces()
         with self.tracer.span(f"chunk [{r0},{r1})", cat="chunk",
                               rounds=r1 - r0) as chunk_sp:
-            args = self._chunk_args(r0, r1)
             if self._server is not None:
                 (state, ef, etas, ress,
                  self._srv, outer) = self._chunk_fn(*args)
@@ -1267,20 +1286,23 @@ class PSEngine:
                 state, ef, etas, ress = self._chunk_fn(*args)
                 outer = None
             jax.block_until_ready(state)
+            chunk_sp.attrs["traces"] = serial_chunk_traces() - traces0
         self._state, self._ef = state, ef
         self.round = r1
+        self.metrics.inc("chunk_traces", chunk_sp.attrs["traces"],
+                         engine="sync")
+        with self.tracer.span(f"{run} telemetry", cat="telemetry"):
+            self._record_rounds(r0, r1, chunk_sp, etas, ress, outer)
 
+    def _record_rounds(self, r0, r1, chunk_sp, etas, ress, outer) -> None:
+        """A ``RoundRecord``, a round span and the metrics of each round
+        of the chunk just run."""
         # Attribute the chunk's wall-clock uniformly across its rounds
         # (dispatch is per-chunk; finer attribution would need per-round
         # host sync, which is exactly what the chunked scan avoids). The
         # timing source is the span layer, not an ad-hoc timer.
         wall = chunk_sp.wall_dur
         per_round_wall = wall / max(r1 - r0, 1)
-        cost = modeled_sync_cost(
-            getattr(self.compressor, "codec_spec", None),
-            self._dense_bytes, workers=self.config.num_workers,
-            backend=self.codec_backend,
-        )
         # Bulk telemetry: the chunk already reduced η to per-round
         # [min, max, mean] on device, so this is one O(rounds) transfer —
         # never O(rounds × fleet) — regardless of fleet size.
@@ -1360,12 +1382,9 @@ class PSEngine:
                         len(alive)), engine="sync",
                     aggregator=self.aggregator.name,
                 )
-            # measured round wall next to the traffic model's prediction
             self.metrics.observe(
                 "round_wall_s", per_round_wall, engine="sync",
                 codec=self.compressor.name, backend=self.codec_backend,
-                modeled_hbm_passes=cost["hbm_passes"],
-                modeled_hbm_s=cost["hbm_s"],
             )
 
     def run(
@@ -1380,21 +1399,23 @@ class PSEngine:
         round scan and writes ``checkpoint_path`` at each boundary."""
         target = self.config.rounds if until_round is None else int(until_round)
         target = min(target, self.config.rounds)
-        with self.tracer.span(f"run [{self.round},{target})", cat="run",
-                              engine="sync"):
+        name = f"run [{self.round},{target})"
+        with self.tracer.span(name, cat="run", engine="sync"):
             while self.round < target:
                 r1 = (min(target, self.round + checkpoint_every)
                       if checkpoint_every else target)
-                self._run_chunk(self.round, r1)
+                self._run_chunk(self.round, r1, name)
                 if checkpoint_path is not None:
                     self.save(checkpoint_path)
-        return self.z_bar()
+            with self.tracer.span(f"{name} z_bar", cat="output"):
+                return self.z_bar()
 
     def step_round(self) -> None:
         """Advance exactly one round (smoke tests, interactive driving)."""
         if self.round >= self.config.rounds:
             raise ValueError("engine already ran all configured rounds")
-        self._run_chunk(self.round, self.round + 1)
+        r = self.round
+        self._run_chunk(r, r + 1, f"run [{r},{r + 1})")
 
     @property
     def state(self) -> PyTree:
