@@ -73,8 +73,9 @@ class ArchConfig:
 
     # --- kernel backends --------------------------------------------------------
     # "reference" = pure-jnp paths; "pallas" routes self-causal attention
-    # through kernels.flash_attention and SSD mixing through kernels.ssd_scan
-    # (forward Pallas, backward via the reference VJP).
+    # through kernels.flash_attention (Pallas forward and backward) and SSD
+    # mixing through kernels.ssd_scan (forward Pallas, backward via the
+    # reference VJP).
     attn_backend: str = "reference"
     ssm_backend: str = "reference"
 

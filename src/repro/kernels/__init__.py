@@ -1,7 +1,8 @@
 """Pallas TPU kernels for the framework's compute hot-spots.
 
 * ``flash_attention`` — blockwise online-softmax attention (causal / sliding
-  window / softcap / GQA), VMEM-tiled via BlockSpec.
+  window / softcap / GQA), VMEM-tiled via BlockSpec, and its
+  FlashAttention-2 backward; ``ops.attention`` is differentiable.
 * ``adaseg_update``  — fused LocalAdaSEG extragradient update kernels
   (explore/anchor/one-shot): η-from-Σ(Z_τ)² computed in-register, box clip
   or two-pass l2-ball projection, and the (Z_t)²/‖G‖² reductions fused into

@@ -1,5 +1,9 @@
-"""Jit'd wrapper for the flash-attention kernel (interpreted on the CPU)
-and automatic sequence padding to the block size.
+"""Jit'd, differentiable wrapper for the flash-attention kernels
+(interpreted on the CPU) and automatic sequence padding to the block size.
+
+The forward kernel's output and row log-sum-exp are the VJP's residuals,
+with q, k and v; the backward kernel recomputes each score tile from them,
+so no S×T array is kept or materialized in either direction.
 
 Examples
 --------
@@ -21,8 +25,28 @@ import jax
 import jax.numpy as jnp
 
 from ..tiling import interpret_mode
-from .kernel import flash_attention
+from .kernel import flash_attention_bwd, flash_attention_fwd
 from .ref import attention_ref
+
+
+def _flash(q, k, v, **kw):
+    """The kernels' attention as a custom VJP: forward kernel, backward
+    kernel. ``kw``: the kernels' static keyword arguments."""
+    kw = dict(kw, interpret=interpret_mode())
+
+    @jax.custom_vjp
+    def f(q, k, v):
+        return flash_attention_fwd(q, k, v, **kw)[0]
+
+    def fwd(q, k, v):
+        o, lse = flash_attention_fwd(q, k, v, **kw)
+        return o, (q, k, v, o, lse)
+
+    def bwd(res, do):
+        return flash_attention_bwd(*res, do, **kw)
+
+    f.defvjp(fwd, bwd)
+    return f(q, k, v)
 
 
 @functools.partial(
@@ -51,8 +75,8 @@ def attention(q, k, v, *, causal=True, window=None, softcap=None, scale=None,
     # for non-causal the window/mask below would need explicit lengths, so we
     # only allow padding in the causal path.
     assert causal or (pad_q == 0 and pad_k == 0)
-    out = flash_attention(
+    out = _flash(
         q, k, v, causal=causal, window=window, softcap=softcap, scale=scale,
-        block_q=bq, block_k=bk, interpret=interpret_mode(),
+        block_q=bq, block_k=bk,
     )
     return out[:, :, :s, :]

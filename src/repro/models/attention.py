@@ -12,7 +12,6 @@ from jax.sharding import PartitionSpec as P
 
 from ..configs.base import ArchConfig
 from ..kernels.flash_attention import ops as flash_ops
-from ..kernels.flash_attention.ref import attention_ref
 from .layers import (
     MODEL,
     _normal,
@@ -137,38 +136,16 @@ def _chunked_attention(q, k, v, *, scale, cap, causal, window, block=1024):
 
 def _flash_self_attention(q, k, v, *, scale, cap, window):
     """Self-causal attention on the model's (B, S, H, D) layout via the
-    Pallas flash kernel (``cfg.attn_backend="pallas"``).
-
-    The kernel has no transpose rule, so the backward pass differentiates
-    the pure-jnp reference (`attention_ref`, validated against the kernel
-    at rtol 1e-5) — forward Pallas, backward reference VJP.
-    """
-    def _ref(q, k, v):
-        out = attention_ref(
-            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3),
-            causal=True, window=window, softcap=cap, scale=scale,
-        )
-        return out.transpose(0, 2, 1, 3)
-
-    @jax.custom_vjp
-    def f(q, k, v):
-        out = flash_ops.attention(
-            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3),
-            causal=True, window=window, softcap=cap, scale=scale,
-        )
-        return out.transpose(0, 2, 1, 3)
-
-    def fwd(q, k, v):
-        return f(q, k, v), (q, k, v)
-
-    def bwd(res, g):
-        _, pull = jax.vjp(_ref, *res)
-        return pull(g)
-
-    f.defvjp(fwd, bwd)
-    return f(q, k, v)
+    Pallas flash kernels (``cfg.attn_backend="pallas"``): the forward
+    kernel, and under autodiff its FlashAttention-2 backward kernel, which
+    recomputes each score tile from the forward's row log-sum-exp
+    (validated against the VJP of `attention_ref`)."""
+    out = flash_ops.attention(
+        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+        v.transpose(0, 2, 1, 3),
+        causal=True, window=window, softcap=cap, scale=scale,
+    )
+    return out.transpose(0, 2, 1, 3)
 
 
 def apply_attention(

@@ -7,7 +7,9 @@ import pytest
 
 from repro.kernels.adaseg_update.kernel import adaseg_update
 from repro.kernels.adaseg_update.ref import adaseg_update_ref
-from repro.kernels.flash_attention.kernel import flash_attention
+from repro.kernels.flash_attention import ops as flash_ops
+from repro.kernels.flash_attention.kernel import (flash_attention,
+                                                  flash_attention_fwd)
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.ssd_scan.kernel import ssd_scan
 from repro.kernels.ssd_scan.ref import ssd_ref
@@ -68,6 +70,109 @@ def test_flash_attention_block_shape_invariance():
     ]
     for o in outs[1:]:
         np.testing.assert_allclose(outs[0], o, rtol=1e-5, atol=1e-5)
+
+
+# (heads, kv heads, seq, attention kwargs, dtype): the backward kernel's
+# features, each a case of one sweep against the VJP of the reference.
+BWD_CASES = {
+    "mha": (4, 4, 128, dict(), jnp.float32),
+    "gqa2": (4, 2, 128, dict(), jnp.float32),
+    "gqa7": (7, 1, 128, dict(), jnp.float32),
+    "window": (4, 2, 128, dict(window=40), jnp.float32),
+    "softcap": (4, 2, 128, dict(softcap=5.0), jnp.float32),
+    "noncausal": (4, 2, 128, dict(causal=False), jnp.float32),
+    "scale": (4, 2, 128, dict(scale=0.3), jnp.float32),
+    "padded": (4, 2, 100, dict(window=40, softcap=5.0), jnp.float32),
+    "bf16": (4, 2, 128, dict(), jnp.bfloat16),
+    "gqa7_bf16": (7, 1, 128, dict(window=40), jnp.bfloat16),
+}
+
+
+def _qkv(key, h, kh, s, d=32, dtype=jnp.float32):
+    ks = jax.random.split(key, 4)
+    q = jax.random.normal(ks[0], (2, h, s, d), dtype)
+    k = jax.random.normal(ks[1], (2, kh, s, d), dtype)
+    v = jax.random.normal(ks[2], (2, kh, s, d), dtype)
+    do = jax.random.normal(ks[3], (2, h, s, d), dtype)
+    return q, k, v, do
+
+
+def _flash_vjp(q, k, v, do, block_q=32, block_k=32, **kw):
+    out, pull = jax.vjp(
+        lambda q, k, v: flash_ops.attention(q, k, v, block_q=block_q,
+                                            block_k=block_k, **kw), q, k, v)
+    return out, pull(do)
+
+
+@pytest.mark.parametrize("case", sorted(BWD_CASES))
+def test_flash_attention_backward_matches_reference_vjp(case):
+    h, kh, s, kw, dtype = BWD_CASES[case]
+    q, k, v, do = _qkv(jax.random.PRNGKey(9), h, kh, s, dtype=dtype)
+    out, grads = _flash_vjp(q, k, v, do, **kw)
+    ref, pull = jax.vjp(lambda q, k, v: attention_ref(q, k, v, **kw), q, k, v)
+    f32 = lambda x: np.asarray(x.astype(jnp.float32))
+    np.testing.assert_allclose(f32(out), f32(ref), **_tol(dtype))
+    for name, g, r in zip("qkv", grads, pull(do)):
+        assert g.dtype == r.dtype == dtype, name
+        np.testing.assert_allclose(f32(g), f32(r), err_msg=name,
+                                   **_tol(dtype))
+
+
+@pytest.mark.parametrize("bq,bk", [(64, 128), (128, 32), (16, 64)])
+def test_flash_attention_backward_block_shape_invariance(bq, bk):
+    """Gradients must not depend on the backward's tiling."""
+    q, k, v, do = _qkv(jax.random.PRNGKey(10), 4, 2, 128)
+    kw = dict(window=48)
+    _, base = _flash_vjp(q, k, v, do, 32, 32, **kw)
+    _, grads = _flash_vjp(q, k, v, do, bq, bk, **kw)
+    for g, b in zip(grads, base):
+        np.testing.assert_allclose(g, b, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("kw", [dict(), dict(window=24, softcap=5.0)],
+                         ids=["causal", "window_softcap"])
+def test_flash_attention_lse_matches_reference_logsumexp(kw):
+    q, k, v, _ = _qkv(jax.random.PRNGKey(11), 4, 2, 128)
+    _, lse = flash_attention_fwd(q, k, v, block_q=32, block_k=32,
+                                 interpret=True, **kw)
+    s = jnp.einsum("bkgsd,bktd->bkgst", q.reshape(2, 2, 2, 128, 32), k)
+    s = s * 32 ** -0.5
+    if "softcap" in kw:
+        s = kw["softcap"] * jnp.tanh(s / kw["softcap"])
+    qi, ki = jnp.arange(128)[:, None], jnp.arange(128)[None, :]
+    mask = (ki <= qi) & (ki > qi - kw.get("window", 128))
+    ref = jax.nn.logsumexp(jnp.where(mask, s, -jnp.inf), axis=-1)
+    np.testing.assert_allclose(lse, ref.reshape(2, 4, 128), rtol=1e-5,
+                               atol=1e-5)
+
+
+def test_flash_attention_grad_materializes_no_score_matrix():
+    """Under ``jax.grad`` the Pallas backend holds no (S, T) array: only
+    (block_q, block_k) tiles inside the kernels."""
+    q, k, v, _ = _qkv(jax.random.PRNGKey(12), 4, 2, 256, d=64)
+
+    def loss(q, k, v):
+        return flash_ops.attention(q, k, v, block_q=128,
+                                   block_k=128).sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+    shapes = []
+
+    def walk(jx):
+        if isinstance(jx, jax.extend.core.ClosedJaxpr):
+            jx = jx.jaxpr
+        for eqn in jx.eqns:
+            shapes.extend(x.aval.shape for x in eqn.invars + eqn.outvars
+                          if hasattr(x.aval, "shape"))
+            for p in eqn.params.values():
+                for sub in p if isinstance(p, (tuple, list)) else [p]:
+                    if isinstance(sub, (jax.extend.core.Jaxpr,
+                                        jax.extend.core.ClosedJaxpr)):
+                        walk(sub)
+
+    walk(jaxpr)
+    assert (128, 128) in {sh[-2:] for sh in shapes}   # the kernels' tiles
+    assert not [sh for sh in shapes if sh[-2:] == (256, 256)]
 
 
 @pytest.mark.parametrize("n", [64, 1000, 4096, 5000])

@@ -77,6 +77,41 @@ def test_serial_chunk_scopes_layer_inside_local_compute(serial_names,
                for n in inside)
 
 
+def pallas_kernels(jaxpr, path=""):
+    """(kernel name, scope path) of every Pallas call in ``jaxpr``, its
+    sub-jaxprs included: the device's custom calls, named by the kernel."""
+    if isinstance(jaxpr, jax.extend.core.ClosedJaxpr):
+        jaxpr = jaxpr.jaxpr
+    for eqn in jaxpr.eqns:
+        here = "/".join(filter(None, [path, str(eqn.source_info.name_stack)]))
+        if eqn.primitive.name == "pallas_call":
+            yield eqn.params["jaxpr"].debug_info.func_name, here
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (tuple, list)) else [p]:
+                if isinstance(sub, (jax.extend.core.Jaxpr,
+                                    jax.extend.core.ClosedJaxpr)):
+                    yield from pallas_kernels(sub, here)
+
+
+def test_attention_backward_kernel_scoped_inside_local_compute():
+    """The Pallas attention backward runs under ``attention`` inside
+    ``local-compute``, where ``attention_ms`` and ``local_compute_ms`` read
+    it; its compiled instruction names, which the update roofline must not
+    read, are checked in ``test_tpu_compile.py``."""
+    eng = tiny_lm_engine()
+    jaxpr = jax.make_jaxpr(eng._chunk_fn)(*eng._chunk_args(0, 1))
+    kernels = list(pallas_kernels(jaxpr))
+    bwd = [p for n, p in kernels if n == "_flash_bwd_kernel"]
+    # one backward a step's oracle call (explore and anchor), and no other
+    # attention kernel than the forward (and its recompute)
+    assert len(bwd) == 2, kernels
+    assert {n for n, p in kernels if component("attention", p)} == {
+        "_flash_kernel", "_flash_bwd_kernel"}
+    for p in bwd:
+        assert p.startswith("local-compute/") and "transpose(" in p, p
+        assert component("attention", p), p
+
+
 def test_serial_chunk_local_compute_stays_plain(serial_names):
     # traceio.in_scope matches a bare component: no wrapper may reach it
     assert any("/local-compute/" in n for n in serial_names)

@@ -20,7 +20,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.adaseg_update import kernel as ak
-from repro.kernels.flash_attention.kernel import flash_attention
+from repro.kernels.flash_attention.kernel import (flash_attention,
+                                                  flash_attention_bwd)
 from repro.kernels.ssd_scan.kernel import ssd_scan
 from repro.kernels.sync_compress import kernel as sk
 
@@ -198,6 +199,59 @@ def test_flash_attention_compiles(one_chip):
     bf16 = jnp.bfloat16
     _compile(fn, [((1, 14, 2048, 64), bf16), ((1, 2, 2048, 64), bf16),
                   ((1, 2, 2048, 64), bf16)], one_chip)
+
+
+@pytest.mark.parametrize("dtype", [F32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_flash_attention_backward_compiles(one_chip, dtype):
+    """The backward at the benchmark cell's widths: 4,096 tokens, 14 query
+    heads sharing 2 KV heads of 64."""
+    s, d = 4096, 64
+    q, kv = ((1, 14, s, d), dtype), ((1, 2, s, d), dtype)
+    fn = functools.partial(flash_attention_bwd, causal=True)
+    text = _compile(fn, [q, kv, kv, q, ((1, 14, s), F32), q], one_chip)
+    # no (S, T) array anywhere in the program: only the kernel's tiles
+    assert f"{s},{s}]" not in text
+
+
+def test_attention_backward_in_the_round_is_scoped_and_unread_by_update(
+        one_chip, monkeypatch):
+    """The tiny Qwen2-style round compiled for the chip: the attention
+    backward's custom calls sit under ``attention`` inside
+    ``local-compute``, and ``traceio.KERNEL`` (the update roofline's
+    reader) matches the update kernels and none of them."""
+    import re
+
+    from perfbench import traceio
+    from repro.kernels.adaseg_update import ops as aops
+    from repro.kernels.flash_attention import ops as fops
+    from repro.kernels.sync_compress import ops as sops
+    from test_scopes import component, tiny_lm_engine
+
+    for mod in (aops, fops, sops):
+        monkeypatch.setattr(mod, "interpret_mode", lambda: False)
+    jax.clear_caches()            # no trace made for the interpreter
+    try:
+        eng = tiny_lm_engine()
+        args = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            eng._chunk_args(0, 1))
+        text = eng._chunk_fn.lower(*args).compile().as_text()
+    finally:
+        jax.clear_caches()
+    calls = [ln.strip() for ln in text.splitlines()
+             if "custom-call(" in ln and "tpu_custom_call" in ln]
+    scope = lambda ln: re.search(r'op_name="([^"]*)"', ln).group(1)
+    attn = [ln for ln in calls if component("attention", scope(ln))
+            and "/local-compute/" in scope(ln)]
+    # forward, its recompute and the backward, for each of the two oracle
+    # calls of a step; the backward is the transpose outside the recompute
+    bwd = [ln for ln in attn if "transpose(" in scope(ln)
+           and "rematted_computation" not in scope(ln)]
+    assert len(attn) == 6 and len(bwd) == 2, attn
+    assert any(traceio.KERNEL.match(ln) for ln in calls)
+    assert not any(traceio.KERNEL.match(ln) for ln in attn)
 
 
 def test_ssd_scan_compiles(one_chip):
