@@ -78,7 +78,9 @@ def first_rounds(cell, n: int = CHECK_ROUNDS) -> dict:
                               jax.device_get(eng.state.grad_sq_sum)]
             snap = jax.device_get(jax.tree.leaves(eng.state.z_tilde))
     sq = jax.jit(lambda a, b: jnp.sum(jnp.square(a - b)))
-    out["change"] = [math.sqrt(float(sq(a, jnp.asarray(b))))
+    # b goes back as a is laid out: on a mesh, each chip gets its workers'
+    # share, not the whole stacked leaf on the first chip.
+    out["change"] = [math.sqrt(float(sq(a, jax.device_put(b, a.sharding))))
                      for a, b in zip(jax.tree.leaves(eng.state.z_tilde),
                                      snap)]
     del snap

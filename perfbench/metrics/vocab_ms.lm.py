@@ -4,6 +4,8 @@ head, its log-softmax and the NLL gather, forward and backward), mean over
 devices."""
 from perfbench import scopes
 
+LAYER = "vocab"       # its scope inside ``local-compute``
+
 
 def read(ctx):
-    return scopes.layer_ms_per_round(ctx, "vocab")
+    return scopes.layer_ms_per_round(ctx, LAYER)

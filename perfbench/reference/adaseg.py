@@ -24,6 +24,12 @@ stream's base and one init key per worker; round keys are
 ``fault`` plants what a broken program would do, for the calibration of
 the limits: ``"no_exchange"`` leaves the sync out (a batch cut in half is
 the sampler's, given by the caller).
+
+Worker m's local steps run on ``devices[m % len(devices)]``, so workers
+that the program spreads over chips fit there in the reference too; the
+sync, the merge and the evaluation run on ``devices[0]``. Where a value
+moves between devices it moves as it is: the arithmetic does not depend on
+the placement.
 """
 from __future__ import annotations
 
@@ -97,7 +103,7 @@ def _leaf_norms(t):
 
 def readings(*, oracle, sample, project, evaluate, init_worker, key,
              workers, local_steps, total_rounds, g0, d_alpha, levels,
-             average_output, rounds=3, fault=None):
+             average_output, rounds=3, fault=None, devices=None):
     """The numbers that decide ``correct``, computed by the reference over
     the first ``rounds`` rounds: the evaluation after each round, √(Σ‖G‖²)
     per worker after round 1 (every oracle gradient of that round as the
@@ -106,15 +112,20 @@ def readings(*, oracle, sample, project, evaluate, init_worker, key,
     the very first oracle gradient (worker 0), which says which leaves the
     gradient reaches at all."""
     m, k = workers, local_steps
+    devs = devices or jax.devices()[:1]
+    home = devs[0]
     keys = jax.random.split(key, m + 1)
     round_keys = jax.random.split(keys[0], total_rounds)
-    z = [project(init_worker(keys[1 + i])) for i in range(m)]
+    z = []
+    for i in range(m):
+        with jax.default_device(devs[i % len(devs)]):
+            z.append(project(init_worker(keys[1 + i])))
     # The uplink residuals wait on the host between syncs.
     e = [jax.tree.map(lambda v: np.zeros(v.shape, np.float32), z[0])
          for _ in range(m)]
     # The output iterate: a running average per worker, or (without
     # averaging) each worker's last z_t, summed into the mean as it comes.
-    zbar = ([jax.tree.map(jnp.zeros_like, z[0]) for _ in range(m)]
+    zbar = ([jax.tree.map(jnp.zeros_like, zi) for zi in z]
             if average_output else None)
     sum_sq = [jnp.float32(0.0)] * m
     grad_sq = [jnp.float32(0.0)] * m
@@ -124,7 +135,9 @@ def readings(*, oracle, sample, project, evaluate, init_worker, key,
     for r in range(rounds):
         rk = round_keys[r]
         if fault != "no_exchange":
-            inv = [1.0 / (d_alpha / jnp.sqrt(g0 ** 2 + s)) for s in sum_sq]
+            inv = [1.0 / (d_alpha
+                          / jnp.sqrt(g0 ** 2 + jax.device_put(s, home)))
+                   for s in sum_sq]
             tot = sum(inv)
             ckeys = jax.random.split(jax.random.fold_in(rk, 7), m)
             leaves0, tdef = jax.tree.flatten(z[0])
@@ -135,8 +148,9 @@ def readings(*, oracle, sample, project, evaluate, init_worker, key,
                 el = jax.tree.leaves(e[i])
                 new_e = []
                 for li in range(len(zl)):
-                    sent, res = _uplink_leaf(zl[li], el[li], inv[i] / tot,
-                                             lkeys[li], levels=levels)
+                    sent, res = _uplink_leaf(
+                        jax.device_put(zl[li], home), el[li], inv[i] / tot,
+                        lkeys[li], levels=levels)
                     merged[li] = merged[li] + sent
                     new_e.append(np.asarray(res))
                 e[i] = jax.tree.unflatten(tdef, new_e)
@@ -147,30 +161,35 @@ def readings(*, oracle, sample, project, evaluate, init_worker, key,
         step_keys = jax.random.split(rk, k * m).reshape(k, m, 2)
         mean = None
         for i in range(m):
-            for s in range(k):
-                zt = None       # the last step's z_t: no longer needed
-                r1, r2 = jax.random.split(step_keys[s, i])
-                eta = d_alpha / jnp.sqrt(g0 ** 2 + sum_sq[i])
-                mt = oracle(z[i], sample(r1))
-                if out["first_grad"] is None:
-                    out["first_grad"] = [float(v) for v in _leaf_norms(mt)]
-                mt_sq = _sq(mt)
-                zt = project(_axpy_leaves(z[i], mt, eta))
-                del mt
-                gt = oracle(zt, sample(r2))
-                grad_sq[i] = grad_sq[i] + _sq(gt) + mt_sq
-                zn = project(_axpy_leaves(z[i], gt, eta))
-                del gt
-                sum_sq[i] = sum_sq[i] + (_sq_diff(zt, z[i])
-                                         + _sq_diff(zt, zn)) / (5 * eta ** 2)
-                t[i] += 1
-                if average_output:
-                    zbar[i] = jax.tree.map(
-                        lambda b, x: b + (x - b) / jnp.float32(t[i]).astype(
-                            x.dtype), zbar[i], zt)
-                z[i] = zn
-            last = zbar[i] if average_output else zt
-            part = jax.tree.map(lambda x: x / m, last)
+            dev = devs[i % len(devs)]
+            z[i] = jax.device_put(z[i], dev)
+            with jax.default_device(dev):
+                for s in range(k):
+                    zt = None       # the last step's z_t: no longer needed
+                    r1, r2 = jax.random.split(step_keys[s, i])
+                    eta = d_alpha / jnp.sqrt(g0 ** 2 + sum_sq[i])
+                    mt = oracle(z[i], sample(r1))
+                    if out["first_grad"] is None:
+                        out["first_grad"] = [float(v)
+                                             for v in _leaf_norms(mt)]
+                    mt_sq = _sq(mt)
+                    zt = project(_axpy_leaves(z[i], mt, eta))
+                    del mt
+                    gt = oracle(zt, sample(r2))
+                    grad_sq[i] = grad_sq[i] + _sq(gt) + mt_sq
+                    zn = project(_axpy_leaves(z[i], gt, eta))
+                    del gt
+                    sum_sq[i] = sum_sq[i] + (
+                        _sq_diff(zt, z[i]) + _sq_diff(zt, zn)) / (5 * eta ** 2)
+                    t[i] += 1
+                    if average_output:
+                        zbar[i] = jax.tree.map(
+                            lambda b, x: b + (x - b) / jnp.float32(
+                                t[i]).astype(x.dtype), zbar[i], zt)
+                    z[i] = zn
+                last = zbar[i] if average_output else zt
+                part = jax.tree.map(lambda x: x / m, last)
+            part = jax.device_put(part, home)
             mean = part if mean is None else jax.tree.map(jnp.add, mean, part)
             del zt, last, part
         out["loss"].append(float(evaluate(mean)))

@@ -14,7 +14,15 @@ one cell's correctness limits sits in a file of its own, found by name:
   traced window.
 
 A later change adds a cell by adding files and entries, never by editing a
-file that is already here.
+file that is already here. A configuration names its ``system``, a module
+of ``systems/`` found by that name (see ``systems/__init__.py``), so a new
+kind of model joins by a module of its own as well.
+
+A cell reports a metric when its name is in that metric's ``workloads``
+list (an end-to-end metric without the list is reported in every cell, a
+per-layer metric without it in every cell that reports the metric it
+moves). A change that adds a cell appends the cell's name to the lists of
+the metrics it reports, and changes no bound and no reader.
 """
 from __future__ import annotations
 

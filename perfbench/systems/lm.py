@@ -1,10 +1,16 @@
-"""Language-model cells: a Qwen2-style decoder trained by LocalAdaSEG
-through ``repro.ps.PSEngine``, built as ``launch.train.make_ps_engine``
-builds it (``ModelWorker`` over ``models.transformer.loss_fn``), with the
-benchmark's token stream as the problem's sampler and the benchmark's
-seeded weights as its init. ``placement: "mesh"`` puts one worker on each
-chip through the engine's ``shard_map`` round; ``"serial"`` stacks the
-workers on one chip."""
+"""Language-model cells: a decoder trained by LocalAdaSEG through
+``repro.ps.PSEngine``, built as ``launch.train.make_ps_engine`` builds it
+(``ModelWorker`` over ``models.transformer.loss_fn``), with the benchmark's
+token stream as the problem's sampler and the benchmark's seeded weights as
+its init. ``placement: "mesh"`` puts one worker on each chip through the
+engine's ``shard_map`` round; ``"serial"`` stacks the workers on one chip.
+
+``LMCell`` is shared by every language-model system; what is particular to
+a model it takes from the system: ``arch(config)``, the program's
+``ArchConfig``; ``reference``, the plain model (``init_params``, ``loss``);
+``counts(config, seq)``, the operations and bytes of its shapes. This
+module is the Qwen2 system and passes Qwen2's three.
+"""
 from __future__ import annotations
 
 import contextlib
@@ -13,15 +19,22 @@ import math
 import jax
 import jax.numpy as jnp
 
-from .. import counts
+from ..counts import adaseg_update_bytes, lm_flops_per_token, lm_params
 from ..reference import adaseg as ref_alg
-from ..reference import qwen2 as ref_model
+from ..reference import qwen2 as reference
 from ..tokens import make_sampler, subkey
 
 ROUNDS_PER_SECOND = 10       # more than any LM cell can run
 EVAL_KEY = 987               # the held-out batch, the same for every seed
 
 VARIANTS = ("bfloat16", "half_batch", "no_exchange")  # control first
+
+# The tests' CPU size: widths and depth over the config's, and the traffic's
+# sequence length.
+TINY = {"hidden_size": 32, "intermediate_size": 64,
+        "num_attention_heads": 2, "num_key_value_heads": 1,
+        "num_hidden_layers": 1, "vocab_size": 128}
+TINY_TRAFFIC = {"seq": 8}
 
 
 def context(config):
@@ -48,13 +61,20 @@ def arch(config):
     )
 
 
+def counts(config, seq):
+    params = lm_params(config)
+    return {"flops_per_token": lm_flops_per_token(config, seq),
+            "update_bytes_per_worker_step": adaseg_update_bytes(params),
+            "params": params}
+
+
 def _alpha(rule, workers):
     return 1.0 / math.sqrt(workers) if rule == "1/sqrt(workers)" else rule
 
 
 class LMCell:
-    def __init__(self, config, traffic, *, seed, seconds, tracer,
-                 engine=True):
+    def __init__(self, config, traffic, *, arch, reference, counts, seed,
+                 seconds, tracer, engine=True):
         from repro.core import AdaSEGConfig, projections
         from repro.core.types import MinimaxProblem
         from repro.models import transformer
@@ -62,6 +82,7 @@ class LMCell:
         from repro.ps import PSConfig, PSEngine, StochasticQuantizeCompressor
 
         self.config, self.traffic, self.seed = config, traffic, seed
+        self.model = reference
         cfg = arch(config)
         cfg.validate()
         m, k = traffic["workers"], traffic["local_steps"]
@@ -76,7 +97,7 @@ class LMCell:
                                    zipf_alpha=tok["zipf_alpha"],
                                    markov_p=tok["markov_p"])
         self.eval_batch = jax.jit(self.sample)(jax.random.PRNGKey(EVAL_KEY))
-        init = jax.jit(lambda key: ref_model.init_params(key, config))
+        init = jax.jit(lambda key: reference.init_params(key, config))
         want = jax.eval_shape(
             lambda: transformer.init_model(jax.random.PRNGKey(0), cfg)[0])
         got = jax.eval_shape(init, jax.random.PRNGKey(0))
@@ -97,7 +118,7 @@ class LMCell:
         self.levels = float(2 ** up["bits"] - 1) if comp else None
         self.total_rounds = 3 + ROUNDS_PER_SECOND * int(seconds) + 50
         mesh = None
-        if engine and traffic["placement"] == "mesh":
+        if traffic["placement"] == "mesh":
             from repro.launch.mesh import make_test_mesh
 
             mesh = make_test_mesh(data=m, model=1)
@@ -115,12 +136,7 @@ class LMCell:
         self.devices = (list(mesh.devices.flat) if mesh is not None
                         else [jax.devices()[0]])
         self.work = {"tokens": m * k * 2 * b * s, "worker_steps": m * k}
-        self.counts = {
-            "flops_per_token": counts.lm_flops_per_token(config, s),
-            "update_bytes_per_worker_step":
-                counts.adaseg_update_bytes(counts.lm_params(config)),
-            "params": counts.lm_params(config),
-        }
+        self.counts = counts(config, s)
 
     def run_round(self, r):
         self.engine.run(until_round=r + 1)
@@ -133,7 +149,7 @@ class LMCell:
         a control (``"bfloat16"``) or a fault (``"half_batch"``: half of
         each batch's tokens left out, the mean taken over the rest;
         ``"no_exchange"``) put in its place."""
-        c, tr = self.config, self.traffic
+        c, tr, ref_model = self.config, self.traffic, self.model
         bf16 = variant == "bfloat16"
         dtype = jnp.bfloat16 if bf16 else jnp.float32
         sample = self.sample
@@ -159,9 +175,10 @@ class LMCell:
                 d_alpha=self.adaseg.diameter * self.adaseg.alpha,
                 levels=self.levels,
                 average_output=self.adaseg.average_output, rounds=rounds,
-                fault=variant if variant == "no_exchange" else None)
+                fault=variant if variant == "no_exchange" else None,
+                devices=self.devices)
 
 
-def build(config, traffic, *, seed, seconds, tracer, engine=True):
-    return LMCell(config, traffic, seed=seed, seconds=seconds, tracer=tracer,
-                  engine=engine)
+def build(config, traffic, **run):
+    return LMCell(config, traffic, arch=arch, reference=reference,
+                  counts=counts, **run)

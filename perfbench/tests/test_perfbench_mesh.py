@@ -1,7 +1,8 @@
-"""The one-chip cell's traffic placed one worker per device through the
-engine's shard_map round (the four-chip deployment the cell is a share of)
-on four virtual CPU devices at a tiny size: a sound run is correct, and
-with the exchange between devices left out it is not."""
+"""The four-chip mix ``dp4-k4-q8-s4096`` as its file states it (one worker
+per device through the engine's shard_map round), under the one-chip cell's
+configuration, on four virtual CPU devices at the system's tiny size: a
+sound run is correct, and with the exchange between devices left out it is
+not. The mix waits in ``traffic/`` for its cell (PERF.md §7)."""
 import json
 import os
 import subprocess
@@ -9,16 +10,19 @@ import sys
 
 from perfbench import spec
 
+TRAFFIC = "dp4-k4-q8-s4096"
+
 SCRIPT = """
 import json, sys
 import tiny_cells
-res = tiny_cells.tiny(tiny_cells.ONE_CHIP[0])
-res["traffic"].update(placement="mesh", workers=4)
+res = tiny_cells.tiny(tiny_cells.ONE_CHIP[0], traffic=sys.argv[1])
+assert res["traffic"]["placement"] == "mesh", res["traffic"]
 sound = tiny_cells.run(res)
 tiny_cells.plant("no_exchange", setattr)
 broken = tiny_cells.run(res)
 print(json.dumps({"sound": sound["correct"], "broken": broken["correct"],
                   "count": sound["device"]["count"],
+                  "workers": res["traffic"]["workers"],
                   "checks": [sound["checks"], broken["checks"]]}))
 """
 
@@ -29,10 +33,10 @@ def test_mesh_cell_sound_and_exchange_left_out():
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
                PYTHONPATH=os.pathsep.join(
                    [here, spec.ROOT, os.path.join(spec.ROOT, "src")]))
-    p = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
+    p = subprocess.run([sys.executable, "-c", SCRIPT, TRAFFIC], env=env,
                        capture_output=True, text=True, timeout=900)
     assert p.returncode == 0, p.stderr[-3000:]
     out = json.loads(p.stdout.strip().splitlines()[-1])
-    assert out["count"] == 4
+    assert out["count"] == 4 and out["workers"] == 4
     assert out["sound"] is True, out["checks"]
     assert out["broken"] is False, out["checks"]

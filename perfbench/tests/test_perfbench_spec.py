@@ -1,12 +1,13 @@
 """BENCHMARK.json keeps to its contract, and every cell resolves by name to
 its configuration, traffic mix, limits and metric readers."""
+import importlib
 import json
 import os
 import re
 
 import pytest
 
-from perfbench import spec
+from perfbench import spec, systems
 
 BENCH = spec.load_benchmark()
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -66,9 +67,15 @@ def test_cell_resolves_by_name(cell):
     res = spec.resolve(BENCH, cell)
     entry = res["config_entry"]
     assert entry["file"].startswith("perfbench/configs/")
-    assert res["config"]["system"] == "lm"
     assert os.path.isfile(os.path.join(spec.HERE, "systems",
                                        res["config"]["system"] + ".py"))
+    system = importlib.import_module(
+        f"perfbench.systems.{res['config']['system']}")
+    missing = [n for n in systems.CONTRACT if not hasattr(system, n)]
+    assert not missing, missing
+    assert callable(system.context) and callable(system.build)
+    assert system.VARIANTS and set(system.TINY) <= set(res["config"])
+    assert set(system.TINY_TRAFFIC) <= set(res["traffic"])
     for key in entry["reduced"]:
         assert key in res["config"]["published"], key
     assert res["limits"] and set(res["limits"]) <= {"loss", "grad", "change"}

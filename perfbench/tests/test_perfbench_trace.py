@@ -66,6 +66,11 @@ def test_update_kernels_by_hand(recorded):
 
 
 def test_per_layer_metrics_of_the_cell(recorded):
+    """Every reader of the cell reads, but one of a layer that the recorded
+    program did not scope yet (the fixture's ``scopes`` say which it
+    carries), which reads nothing. The top-level layers' times are
+    disjoint, so they sum to less than a round; the layers nested in the
+    local step sum to no more than it."""
     meta, tr = recorded
     res = spec.resolve(spec.load_benchmark(), meta["workload"])
     system = importlib.import_module(
@@ -76,13 +81,24 @@ def test_per_layer_metrics_of_the_cell(recorded):
     window = harness.Window(3, [w / meta["rounds"]] * meta["rounds"], w)
     ctx = harness.LayerContext(tr, window, cell, peaks(meta["device_kind"]),
                                [0])
-    got = {m["name"]: spec.load_module("metrics", m["name"]).read(ctx)
-           for m in res["per_layer"]}
+    readers = {m["name"]: spec.load_module("metrics", m["name"])
+               for m in res["per_layer"]}
+    got = {k: r.read(ctx) for k, r in readers.items()}
+    nested = {k for k, r in readers.items() if hasattr(r, "LAYER")}
+    for k in nested:
+        if readers[k].LAYER not in meta["scopes"]:
+            assert got[k] is None, (k, got[k])
+            del got[k]
     assert all(v is not None for v in got.values()), got
     per_round_ms = 1e3 * w / meta["rounds"]
-    scoped = [v for k, v in got.items() if k.split(".")[0].endswith("_ms")]
-    assert scoped and all(v > 0 for v in scoped)
-    assert sum(scoped) < per_round_ms
+    timed = {k: v for k, v in got.items()
+             if k.split(".")[0].endswith("_ms")}
+    assert timed and all(v > 0 for v in timed.values())
+    assert sum(v for k, v in timed.items() if k not in nested) < per_round_ms
+    local = [v for k, v in timed.items()
+             if k.split(".")[0] == "local_compute_ms"]
+    assert len(local) == 1
+    assert sum(v for k, v in timed.items() if k in nested) <= local[0]
     for k, v in got.items():
         if k.startswith("idle_share"):
             assert 0.0 <= v < 100.0

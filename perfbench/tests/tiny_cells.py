@@ -1,25 +1,29 @@
 """Cells of BENCHMARK.json cut to a size the CPU runs in seconds, for the
-tests that drive a whole run with the chip check skipped."""
+tests that drive a whole run with the chip check skipped. Each system gives
+its own tiny sizes (``TINY``, ``TINY_TRAFFIC``)."""
+import importlib
+import json
+import os
 import time
 
 from perfbench import harness, spec
 
-TINY = {
-    "lm": {"hidden_size": 32, "intermediate_size": 64,
-           "num_attention_heads": 2, "num_key_value_heads": 1,
-           "num_hidden_layers": 1, "vocab_size": 128},
-}
-TINY_TRAFFIC = {"lm": {"seq": 8}}
 ONE_CHIP = [w["name"] for w in spec.load_benchmark()["workloads"]
             if w["chips"] == 1]
 
 
-def tiny(cell: str) -> dict:
-    """A cell of BENCHMARK.json at a tiny size."""
+def tiny(cell: str, traffic: str | None = None) -> dict:
+    """A cell of BENCHMARK.json at its system's tiny size; ``traffic``
+    names another mix of ``traffic/`` to run its configuration under (one
+    kept for a cell that BENCHMARK.json does not hold yet)."""
     res = spec.resolve(spec.load_benchmark(), cell)
-    system = res["config"]["system"]
-    res["config"].update(TINY[system])
-    res["traffic"].update(TINY_TRAFFIC[system])
+    if traffic is not None:
+        with open(os.path.join(spec.HERE, "traffic", f"{traffic}.json")) as f:
+            res["traffic"] = json.load(f)
+    system = importlib.import_module(
+        f"perfbench.systems.{res['config']['system']}")
+    res["config"].update(system.TINY)
+    res["traffic"].update(system.TINY_TRAFFIC)
     return res
 
 
@@ -35,7 +39,9 @@ def plant(fault: str, patch) -> None:
 
     * ``unchanged`` — a local step returns its state as it was;
     * ``half_batch`` — the model's loss reads half of each batch's tokens
-      (the first half of every sequence), the mean taken over them;
+      (the first half of every sequence), the mean taken over them: it
+      patches ``repro.models.transformer.loss_fn``, which every LM system's
+      oracle calls through the module;
     * ``no_exchange`` — the sync delivers nothing: every worker keeps its
       own anchor (on a mesh, the exchange between chips left out).
     """
